@@ -15,12 +15,12 @@ Protocol (interleaved A/B, min of warm reps — benchmarks/common.py):
 The grid is shaped to stress exactly what changed: lineage-tagged AIMM
 lanes (agent staging + store write-backs on the landing path) across
 several topologies plus a ragged baseline group (>= 4 compiled groups, so
-async landing has device work to hide behind).  On CPU `auto` resolves the
-epoch backend to the jnp path, so the A/B improvement here measures the
-pipelining + staging work; the fused Pallas kernel is recorded separately
-as *parity rows* (interpret-mode wall time + bit-identity vs jnp) with no
-speedup claim — interpret mode is a correctness vehicle, and the Mosaic
-lane is future work (ROADMAP).
+async landing has device work to hide behind).  `auto` resolves the epoch
+backend to the jnp path on every platform, so the A/B improvement here
+measures the pipelining + staging work; the fused Pallas kernel is
+recorded separately as *parity rows* (interpret-mode wall time +
+bit-identity vs jnp) with no speedup claim — the TPU compiler refuses the
+kernel (kernels/epoch_fused/kernel.py).
 
 Also recorded: a store-stacking microbench (`_warm_agent_batch` on a
 prewarmed store, staging buffers vs historical per-cell device stacking)

@@ -12,6 +12,7 @@ import os
 import jax
 
 from benchmarks.bench_engine import _grid
+from repro.compile_cache import enable_compile_cache
 
 LOG_DIR = os.environ.get("PROFILE_DIR", "bench_out/profile")
 
@@ -29,4 +30,5 @@ def run():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -3,10 +3,15 @@
 Prints ``name,us_per_call,derived`` CSV. Env:
   BENCH_FULL=1   paper-scale traces/episodes (slower)
   BENCH_ONLY=fig6,fig9  run a subset
+Exits non-zero when any selected module raised (its ERROR row is still
+printed and the remaining modules still run).
 """
+import importlib
 import os
 import sys
 import traceback
+
+from repro.compile_cache import enable_compile_cache
 
 MODULES = [
     ("engine_sweep", "benchmarks.bench_engine"),
@@ -31,21 +36,25 @@ MODULES = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    """Run the selected modules; returns the number that failed."""
     only = os.environ.get("BENCH_ONLY")
     wanted = only.split(",") if only else None
     print("name,us_per_call,derived")
+    failed = 0
     for tag, mod_name in MODULES:
         if wanted and not any(w in tag for w in wanted):
             continue
         try:
-            import importlib
             mod = importlib.import_module(mod_name)
             mod.run()
         except Exception as e:
             traceback.print_exc(file=sys.stderr)
             print(f"{tag}/ERROR,0,{type(e).__name__}", flush=True)
+            failed += 1
+    return failed
 
 
 if __name__ == '__main__':
-    main()
+    enable_compile_cache()
+    sys.exit(1 if main() else 0)

@@ -9,6 +9,7 @@ of a serial run per cell.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.nmp import NMPConfig
 from repro.nmp.scenarios import single_program_grid
 from repro.nmp.sweep import run_grid
@@ -47,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -4,6 +4,7 @@
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.nmp import NMPConfig, make_trace, run_episode, run_program
 from repro.nmp.stats import summarize
 
@@ -33,4 +34,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

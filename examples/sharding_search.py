@@ -13,6 +13,7 @@ row per cell with the RL-vs-exhaustive optimality gap.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, SHAPES, get_config
 from repro.core.sharding_mapper import Knobs, exhaustive_best, search
 
@@ -68,4 +69,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import AttnCfg, ModelConfig
 from repro.models import build_model, count_params
 from repro.train.data import DataConfig, SyntheticDataset
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

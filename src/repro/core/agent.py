@@ -10,8 +10,7 @@ The agent is a NamedTuple of arrays (scan-compatible). Per invocation:
 only the environment state is cleared between runs (see nmp.engine.run_program).
 The engine invokes the whole observe -> train -> act pipeline only on
 invocation epochs (under `jax.lax.cond`); epochs between invocations carry
-the agent through untouched.  Gradient-free inference (act, TD targets) can
-route through the fused Pallas dueling-qnet kernel (see core.dqn.q_values_infer).
+the agent through untouched.
 
 Lifecycle API (the continual layer, nmp.continual, builds on these):
 
@@ -29,6 +28,7 @@ first handoff, so single-scenario behavior is unchanged.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, NamedTuple
 
 import jax
@@ -65,10 +65,18 @@ class AgentConfig(NamedTuple):
     min_replay: int = 32
 
 
+def optimizer(cfg: AgentConfig):
+    """Adam with the gradient clip's global norm summed in the Q-network's
+    fixed order (`dqn.tree_sum`), so a TD step's bits do not depend on how
+    many cells are batched with it."""
+    return adamw(cfg.dqn.lr, grad_clip=cfg.dqn.grad_clip,
+                 leaf_sum=lambda g: dqn.tree_sum(g.reshape(-1), 0))
+
+
 def init_agent(rng: jax.Array, cfg: AgentConfig) -> AgentState:
     k1, k2 = jax.random.split(rng)
     params = dqn.init_params(k1, cfg.dqn)
-    opt = adamw(cfg.dqn.lr, grad_clip=cfg.dqn.grad_clip)
+    opt = optimizer(cfg)
     return AgentState(
         params=params,
         target_params=jax.tree.map(jnp.copy, params),
@@ -82,10 +90,13 @@ def init_agent(rng: jax.Array, cfg: AgentConfig) -> AgentState:
     )
 
 
+@partial(jax.jit, static_argnums=1)
 def cold_start(seed, cfg: AgentConfig) -> AgentState:
     """The engine's fresh-agent convention: one agent per scenario seed,
     keyed off PRNGKey(seed + 1).  `seed` may be a traced scalar (the sweep
-    cold-starts whole lanes inside jit)."""
+    cold-starts whole lanes inside jit).  Always compiled: op-by-op
+    execution rounded the initial weights differently from the sweep's
+    compiled cold start on the TPU, so serial and batched lanes diverged."""
     return init_agent(jax.random.PRNGKey(seed + 1), cfg)
 
 
@@ -116,7 +127,7 @@ def agent_template(cfg: AgentConfig) -> AgentState:
     restore targets are built from this, so a fresh process can restore an
     agent without replaying the init RNG."""
     params = dqn.zeros_params(cfg.dqn)
-    opt = adamw(cfg.dqn.lr, grad_clip=cfg.dqn.grad_clip)
+    opt = optimizer(cfg)
     return AgentState(
         params=params,
         target_params=jax.tree.map(jnp.copy, params),
@@ -144,7 +155,7 @@ def act(agent: AgentState, cfg: AgentConfig, state_vec: jnp.ndarray,
     way, so greedy evaluation stays reproducible against static calls.
     """
     rng, k_eps, k_act = jax.random.split(agent.rng, 3)
-    q = dqn.q_values_infer(agent.params, state_vec, cfg.dqn)
+    q = dqn.q_values(agent.params, state_vec, cfg.dqn)
     greedy = jnp.argmax(q).astype(jnp.int32)
     # ε decays over the agent's *lifetime* (global_step survives scenario
     # handoffs); for a cold-started agent global_step == step, so cold
@@ -183,7 +194,7 @@ def train_step(agent: AgentState, cfg: AgentConfig,
     """`train` with the minibatch RNG drawn by the caller (`agent.rng` is not
     consumed here, so the engine can advance the stream unconditionally and
     gate the expensive TD step itself behind `lax.cond`)."""
-    opt = adamw(cfg.dqn.lr, grad_clip=cfg.dqn.grad_clip)
+    opt = optimizer(cfg)
     batch = sample(agent.replay, rng, cfg.dqn.batch_size)
     ready = (agent.replay.size >= cfg.min_replay).astype(jnp.float32)
     batch = dict(batch, w=batch["w"] * ready)

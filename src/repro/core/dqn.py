@@ -10,7 +10,6 @@ inside a single `jax.lax.scan`.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Any
 
 import jax
@@ -62,111 +61,118 @@ def zeros_params(cfg: DQNConfig) -> PyTree:
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
+# ---------------------------------------------------------------------------
+# Order-fixed arithmetic
+# ---------------------------------------------------------------------------
+# XLA picks a matmul algorithm and a reduction order per shape and layout.
+# On the TPU the same lane's Q-network therefore computed different bits
+# when vmapped alone (the serial reference) and among other lanes (the
+# sweep), and learned lanes drifted apart within a few episodes.  Every sum
+# the network and its gradients take is instead a fixed binary tree of
+# elementwise f32 adds (so is the agent's gradient clip: `core.agent`
+# passes `tree_sum` to `adamw`).  Products pass an optimization barrier
+# (`_product`) before they are summed, which asks the compiler not to fuse
+# a multiply into the adds (a fused multiply-add rounds once, not twice).
+# Both are requests to the compiler, not guarantees.  What was checked: on
+# a TPU v5e, act Q and a TD step at vmap widths 1, 2, 7 and 21 equal width
+# 27 bit for bit; on the CPU, tests/test_dqn.py pins the same at widths 1,
+# 2 and 27 with weights entering the program as inputs, as the sweep's scan
+# carry holds them.  A weight computed in the same program as the forward
+# may still round differently there (seen on the CPU with the init fused
+# into the forward).
+
+def _product(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.optimization_barrier(x * y)
+
+
+def tree_sum(x: jnp.ndarray, axis: int, keepdims: bool = False
+             ) -> jnp.ndarray:
+    """Sum over `axis` by pairwise halving (zero-padded to even lengths)."""
+    x = jnp.moveaxis(x, axis, 0)
+    while x.shape[0] > 1:
+        if x.shape[0] % 2:
+            x = jnp.concatenate([x, jnp.zeros_like(x[:1])])
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return jnp.moveaxis(x, 0, axis) if keepdims else x[0]
+
+
+@jax.custom_vjp
+def dense(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """x @ w + b for x (B, K), w (K, N), b (N,), in a fixed summation order
+    (its gradients too: the transpose of a broadcast would be an XLA
+    reduction)."""
+    return tree_sum(_product(x[:, :, None], w), 1) + b
+
+
+def _dense_fwd(x, w, b):
+    return dense(x, w, b), (x, w)
+
+
+def _dense_bwd(res, g):
+    x, w = res
+    return (tree_sum(_product(g[:, None, :], w), 2),
+            tree_sum(_product(x[:, :, None], g[:, None, :]), 0),
+            tree_sum(g, 0))
+
+
+dense.defvjp(_dense_fwd, _dense_bwd)
+
+
+@jax.custom_vjp
+def dueling_head(v: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
+    """Q = V + A - mean_a A for v (B, 1), a (B, A)."""
+    return v + (a - tree_sum(a, -1, keepdims=True) / a.shape[-1])
+
+
+def _dueling_fwd(v, a):
+    return dueling_head(v, a), None
+
+
+def _dueling_bwd(_, g):
+    g_sum = tree_sum(g, -1, keepdims=True)
+    return g_sum, g - g_sum / g.shape[-1]
+
+
+dueling_head.defvjp(_dueling_fwd, _dueling_bwd)
+
+
 def q_values(params: PyTree, state: jnp.ndarray, cfg: DQNConfig) -> jnp.ndarray:
     """Q(s, .) for a single state (state_dim,) or batch (B, state_dim)."""
     squeeze = state.ndim == 1
     x = jnp.atleast_2d(state.astype(jnp.float32))
     i = 0
     while f"w{i}" in params:
-        x = jnp.maximum(x @ params[f"w{i}"] + params[f"b{i}"], 0.0)
+        x = jnp.maximum(dense(x, params[f"w{i}"], params[f"b{i}"]), 0.0)
         i += 1
     if cfg.dueling:
-        v = x @ params["w_v"] + params["b_v"]                     # (B, 1)
-        a = x @ params["w_a"] + params["b_a"]                     # (B, A)
-        q = v + a - jnp.mean(a, axis=-1, keepdims=True)
+        q = dueling_head(dense(x, params["w_v"], params["b_v"]),   # (B, 1)
+                         dense(x, params["w_a"], params["b_a"]))   # (B, A)
     else:
-        q = x @ params["w_q"] + params["b_q"]
+        q = dense(x, params["w_q"], params["b_q"])
     return q[0] if squeeze else q
-
-
-QNET_BACKENDS = ("auto", "pallas", "jnp")
-
-
-def _validate_backend(mode: str, source: str) -> str:
-    if mode not in QNET_BACKENDS:
-        raise ValueError(
-            f"{source}={mode!r} is not a valid qnet backend; expected one of "
-            f"{QNET_BACKENDS}. 'auto' picks the fused Pallas kernel on TPU "
-            "and jnp elsewhere; 'pallas' forces the kernel (interpret mode "
-            "off-TPU); 'jnp' forces the plain XLA path.")
-    return mode
-
-
-def _resolve_auto(mode: str) -> str:
-    """The `auto` policy: the fused Pallas kernel on TPU, plain jnp elsewhere
-    (single definition shared by the env-var default and explicit args)."""
-    if mode == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
-    return mode
-
-
-def _infer_backend() -> str:
-    """Backend for gradient-free Q inference.
-
-    `REPRO_QNET_BACKEND` ∈ {auto, pallas, jnp}: `auto` picks the fused Pallas
-    kernel on TPU (the paper's §5.2 RL-accelerator analogue) and plain jnp
-    elsewhere; `pallas` forces the kernel (interpret mode off-TPU — used by
-    the wiring tests, slow on CPU).  Unknown values raise (validated here and
-    eagerly at import below) rather than silently falling back to jnp.  Read
-    at trace time: flipping the env var does not invalidate already-jitted
-    programs.
-    """
-    return _resolve_auto(_validate_backend(
-        os.environ.get("REPRO_QNET_BACKEND", "auto"), "REPRO_QNET_BACKEND"))
-
-
-# Fail fast on a typo'd override: a bad REPRO_QNET_BACKEND should abort at
-# import, not silently run the wrong backend deep inside a jitted sweep.
-_validate_backend(os.environ.get("REPRO_QNET_BACKEND", "auto"),
-                  "REPRO_QNET_BACKEND")
-
-
-def fused_kernel_compatible(params: PyTree) -> bool:
-    """The fused Pallas kernel covers the production shape: dueling head over
-    exactly two hidden layers."""
-    return "w_v" in params and "w1" in params and "w2" not in params
-
-
-def q_values_infer(params: PyTree, state: jnp.ndarray, cfg: DQNConfig,
-                   backend: str | None = None) -> jnp.ndarray:
-    """Q(s, .) for inference-only consumers (action selection, TD targets).
-
-    Numerically equivalent to `q_values` but free to route through the fused
-    Pallas dueling-qnet kernel (one launch for the whole batch, weights
-    resident in VMEM) since no gradient flows through it.
-    """
-    backend = (_infer_backend() if backend is None
-               else _resolve_auto(_validate_backend(backend, "backend")))
-    if backend == "pallas" and fused_kernel_compatible(params):
-        from repro.kernels.dueling_qnet.ops import qnet_forward
-        squeeze = state.ndim == 1
-        x = jnp.atleast_2d(state.astype(jnp.float32))
-        q = qnet_forward(params, x)
-        return q[0] if squeeze else q
-    return q_values(params, state, cfg)
 
 
 def td_loss(params: PyTree, target_params: PyTree, batch: dict, cfg: DQNConfig) -> jnp.ndarray:
     """Squared TD error (paper eq. 3), double-DQN target if cfg.double.
 
     Only the Q(s, a) term carries gradients; the target-network values and the
-    double-DQN argmax selection are inference (stop_gradient) and go through
-    `q_values_infer`, i.e. the fused Pallas kernel where available.
+    double-DQN argmax selection are inference (stop_gradient).
     """
     q = q_values(params, batch["s"], cfg)                          # (B, A)
     q_sa = jnp.take_along_axis(q, batch["a"][:, None], axis=1)[:, 0]
     q_next_t = jax.lax.stop_gradient(
-        q_values_infer(target_params, batch["s2"], cfg))           # (B, A)
+        q_values(target_params, batch["s2"], cfg))                 # (B, A)
     if cfg.double:
-        q_next_o = jax.lax.stop_gradient(
-            q_values_infer(params, batch["s2"], cfg))
+        q_next_o = jax.lax.stop_gradient(q_values(params, batch["s2"], cfg))
         a_star = jnp.argmax(q_next_o, axis=-1)
         q_next = jnp.take_along_axis(q_next_t, a_star[:, None], axis=1)[:, 0]
     else:
         q_next = jnp.max(q_next_t, axis=-1)
     y = batch["r"] + cfg.gamma * (1.0 - batch["done"]) * q_next
     err = (y - q_sa) * batch["w"]          # `w` masks invalid (not-yet-filled) samples
-    return jnp.sum(jnp.square(err)) / jnp.maximum(jnp.sum(batch["w"]), 1.0)
+    return (tree_sum(jnp.square(err), 0)
+            / jnp.maximum(tree_sum(batch["w"], 0), 1.0))
 
 
 def num_params(cfg: DQNConfig) -> int:

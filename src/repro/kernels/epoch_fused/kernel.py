@@ -19,10 +19,19 @@ trace-time-constant operands (topology tensors) ride along unbatched.
 The kernel body executes the exact same stage functions as the jnp dispatch
 path (`ref.shared_stage` / `ref.route_stage_onehot` / `ref.tom_stage_loop`),
 so interpret-mode output is bit-identical to the jnp path on the pinned
-engine goldens (tests/test_pallas_parity.py).  Remaining work for the
-real-TPU (Mosaic) lane: the P-indexed gathers/scatters and `lax.top_k`
-inside the body lower cleanly in interpreter mode everywhere but still need
-a tiled formulation for Mosaic — tracked in ROADMAP.md.
+engine goldens (tests/test_pallas_parity.py).
+
+The TPU compiler (Mosaic, JAX 0.9.0, compiled for a v5e) refuses this
+kernel, so it runs in interpreter mode only and `auto` never selects it:
+
+  * shared stage: `NotImplementedError: Unimplemented primitive in Pallas
+    TPU lowering: scatter-max` — the row-buffer stamp `rb_stamp.at[...].max`
+    in `ref.shared_stage`;
+  * route stage and `tom_scores_call`: `NotImplementedError: Only 2D gather
+    is supported` — the P-indexed effective-table and route-row gathers.
+
+A port needs a tiled formulation (scatter via one-hot matmul tiles, a
+streaming top-k) — tracked in ROADMAP.md.
 """
 from __future__ import annotations
 

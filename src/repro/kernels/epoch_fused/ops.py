@@ -2,16 +2,20 @@
 
 `REPRO_EPOCH_BACKEND` selects how the epoch simulation core executes:
 
-  auto             pallas on TPU, jnp elsewhere (the default)
-  jnp              the historical gather/einsum path (bit-exact reference)
-  pallas           the fused kernel (interpret-mode off-TPU, so it runs —
-                   and stays bit-identical — on any backend)
+  auto             jnp on every platform (the default)
+  jnp              the gather/einsum path, compiled by XLA (bit-exact
+                   reference and the path that runs on the chip)
+  pallas           the fused kernel, compiled by Mosaic on a TPU (interpret
+                   mode elsewhere).  The TPU compiler currently refuses it —
+                   the shared stage's row-buffer stamp scatter-max and the
+                   route/TOM stages' P-indexed gathers (see kernel.py) — and
+                   that error surfaces unchanged at compile time
   pallas_interpret the fused kernel forced into interpreter mode everywhere
-                   (the CI parity lane)
+                   (the parity oracle of the CPU tests)
 
 The knob is validated eagerly at import AND at every resolve, raising a
-ValueError that names the knob and the offending value (same contract as
-`REPRO_QNET_BACKEND` in repro.core.dqn).  The resolved backend is carried
+ValueError that names the knob and the offending value.  The resolved
+backend is carried
 in `engine.BodyFlags.epoch_backend` — a static jit argument — so flipping
 the env var between calls selects a distinct compiled program instead of
 being silently frozen into a resident one.
@@ -38,7 +42,7 @@ def _validate_backend(mode: str, source: str) -> str:
     if mode not in EPOCH_BACKENDS:
         raise ValueError(
             f"{source}={mode!r} is not a valid epoch backend; expected one "
-            f"of {EPOCH_BACKENDS} (auto = pallas on TPU / jnp elsewhere; "
+            f"of {EPOCH_BACKENDS} (auto = jnp on every platform; "
             f"pallas_interpret forces the kernel's interpreter mode on any "
             f"backend)")
     return mode
@@ -59,9 +63,7 @@ def resolve_backend(mode: str | None = None) -> str:
         mode = _validate_backend(os.environ.get(ENV_KNOB, "auto"), ENV_KNOB)
     else:
         _validate_backend(mode, "epoch backend")
-    if mode == "auto":
-        return "pallas" if _on_tpu() else "jnp"
-    return mode
+    return "jnp" if mode == "auto" else mode
 
 
 def _interpret(backend: str) -> bool:

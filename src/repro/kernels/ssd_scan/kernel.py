@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes TPU compiler options as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, r_scr, *,
                 chunk: int, n_heads: int, d_state: int, head_dim: int):
@@ -97,6 +94,6 @@ def ssd_scan(x, b, c, dt, a, *, chunk: int = 128, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((Bsz, L, H, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((H, N, P), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(x, b, c, dt, a)

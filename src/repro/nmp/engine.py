@@ -51,11 +51,13 @@ count, OPC-ring length, PEI hot-page sort index, technique, mapper, forced
 action, exploration flag -- is carried as a traced `TraceCtx` scalar instead,
 and every state update is gated on `has_ops`, so epochs past the end of a
 (padded) trace are exact no-ops.  The epoch body itself is written per-lane
-and `jax.vmap`ed over a scenario axis (the serial runner is the same body at
-batch size 1), with the epoch scan *outside* the vmap so the
+and `jax.vmap`ed over a (lane, seed) grid (the serial runner is the same
+body on a 1 x 1 grid), with the epoch scan *outside* the vmap so the
 any-lane-invokes `lax.cond` is a genuine scalar branch; seed replicas of a
-lane ride an inner seed-axis vmap that shares the lane's trace arrays
-(`seed_axis=True` in `_epoch_batched`).  That makes one compiled program
+lane ride the inner seed-axis vmap, which shares the lane's trace arrays.
+The serial runner keeps the grid's layout rather than a plain lane vmap
+because on the TPU a different layout rounds the learned agent's state
+features differently.  That makes one compiled program
 valid for a whole stacked grid of scenarios -- shardable over a device mesh
 along the lane axis -- and keeps the batched engine bit-identical to serial
 runs (tests/test_sweep_equivalence.py, tests/test_engine_golden.py,
@@ -136,10 +138,12 @@ class BodyFlags(NamedTuple):
 
     `epoch_backend` is the resolved REPRO_EPOCH_BACKEND (one of jnp /
     pallas / pallas_interpret — see repro.kernels.epoch_fused.ops): the
-    epoch simulation core runs either as the historical gather/einsum jnp
-    path or as the fused Pallas kernel.  Carrying it here (a static jit
-    argument everywhere flags flow) means flipping the knob selects a
-    distinct compiled program instead of being frozen into a resident one."""
+    epoch simulation core runs either as the gather/einsum jnp path (what
+    `auto` resolves to on every platform, TPU included) or as the fused
+    Pallas kernel, an explicit opt-in that the TPU compiler refuses today.
+    Carrying it here (a static jit argument everywhere flags flow) means
+    flipping the knob selects a distinct compiled program instead of being
+    frozen into a resident one."""
     has_agent: bool = False     # a live DQN (aimm lanes with a learned policy)
     any_aimm: bool = False      # hot-page selection / action application
     any_tom: bool = False       # TOM candidate scoring + commit
@@ -948,11 +952,10 @@ def _epoch_batched(env: EnvState, agent: AgentState | None, trace: dict,
                    rw_pages: jnp.ndarray, tom_cands: jnp.ndarray,
                    ctx: TraceCtx, cfg: NMPConfig, spec: StateSpec,
                    agent_cfg: AgentConfig, flags: BodyFlags,
-                   agent_gate: str = "cond", tom_gate: str = "cond",
-                   seed_axis: bool = False):
+                   agent_gate: str = "cond", tom_gate: str = "cond"):
     """One epoch over a (B, ...) batch of lanes.
 
-    With `seed_axis=True` the env (and per-cell EpochMid/metrics) carry a
+    The env (and per-cell EpochMid/metrics) carry a
     (B, S) (lane, seed) grid while the trace / rw_pages / TraceCtx stay
     per-lane (B, ...): the cost-model halves are nested-vmapped with the
     trace axis unmapped over seeds, so S seed replicas of a lane share one
@@ -967,13 +970,13 @@ def _epoch_batched(env: EnvState, agent: AgentState | None, trace: dict,
     profiling phase" (`tom_gate="masked"` forces the score-every-epoch
     reference path).
 
-    With `flags.share_seed_inv` (seed grids only) the seed-invariant half of
+    With `flags.share_seed_inv` the seed-invariant half of
     the cost model is computed once per lane from the seed-0 env slice
     (`_shared_epoch`; every quantity in it evolves identically across seed
     replicas) and broadcast into the inner seed vmap with `in_axes=None` —
     S replicas share one window fetch / stamp scatter / PEI top_k, and TOM's
     profiling scorer runs per lane instead of per cell."""
-    share = seed_axis and flags.share_seed_inv
+    share = flags.share_seed_inv
     env0 = jax.tree.map(lambda a: a[:, 0], env) if share else None
 
     if flags.any_tom:
@@ -984,13 +987,13 @@ def _epoch_batched(env: EnvState, agent: AgentState | None, trace: dict,
                                       flags.epoch_backend)
 
         score_env = env0 if share else env
-        vscores = (jax.vmap(jax.vmap(scores_fn, in_axes=(0, None, None)))
-                   if seed_axis and not share else jax.vmap(scores_fn))
+        vscores = (jax.vmap(scores_fn) if share else
+                   jax.vmap(jax.vmap(scores_fn, in_axes=(0, None, None))))
         phase = (score_env.epochs.astype(jnp.int32)
                  % (K + TOM_COMMIT_WINDOWS))             # (B,) / (B, S)
         is_tom_b = ctx.mapper == MAPPER_ID["tom"]
         n_ops_b = ctx.n_ops
-        if seed_axis and not share:
+        if not share:
             is_tom_b, n_ops_b = is_tom_b[:, None], n_ops_b[:, None]
         profiling = is_tom_b & (phase < K) & (score_env.op_ptr < n_ops_b)
         if tom_gate == "cond":
@@ -1006,27 +1009,23 @@ def _epoch_batched(env: EnvState, agent: AgentState | None, trace: dict,
     def sim_fn(e, t, c, ts):
         return _epoch_sim(e, t, tom_cands, c, cfg, spec, agent_cfg, flags, ts)
 
-    if seed_axis:
-        if share:
-            shared = jax.vmap(
-                lambda e, t, c, ts: _shared_epoch(e, t, c, cfg, flags, ts))(
-                    env0, trace, ctx, tom_scores_all)
+    if share:
+        shared = jax.vmap(
+            lambda e, t, c, ts: _shared_epoch(e, t, c, cfg, flags, ts))(
+                env0, trace, ctx, tom_scores_all)
 
-            def sim_sh(e, t, c, sh):
-                return _epoch_sim(e, t, tom_cands, c, cfg, spec, agent_cfg,
-                                  flags, shared=sh)
+        def sim_sh(e, t, c, sh):
+            return _epoch_sim(e, t, tom_cands, c, cfg, spec, agent_cfg,
+                              flags, shared=sh)
 
-            sim = jax.vmap(jax.vmap(sim_sh, in_axes=(0, None, None, None)))(
-                env, trace, ctx, shared)
-        else:
-            sim = jax.vmap(jax.vmap(sim_fn, in_axes=(0, None, None, 0)))(
-                env, trace, ctx, tom_scores_all)
-        B, S = sim.invoke.shape
-        flat = lambda a: a.reshape((B * S,) + a.shape[2:])
-        rep = lambda a: jnp.repeat(a, S, axis=0)         # per-lane -> per-cell
+        sim = jax.vmap(jax.vmap(sim_sh, in_axes=(0, None, None, None)))(
+            env, trace, ctx, shared)
     else:
-        sim = jax.vmap(sim_fn)(env, trace, ctx, tom_scores_all)
-        flat = rep = lambda a: a
+        sim = jax.vmap(jax.vmap(sim_fn, in_axes=(0, None, None, 0)))(
+            env, trace, ctx, tom_scores_all)
+    B, S = sim.invoke.shape
+    flat = lambda a: a.reshape((B * S,) + a.shape[2:])
+    rep = lambda a: jnp.repeat(a, S, axis=0)             # per-lane -> per-cell
 
     is_aimm = rep(ctx.mapper == MAPPER_ID["aimm"])       # flat (B*S,)
     forced = rep(ctx.forced_action)
@@ -1058,27 +1057,24 @@ def _epoch_batched(env: EnvState, agent: AgentState | None, trace: dict,
     def apply_fn(e, m, a, r, c):
         return _epoch_apply(e, m, a, r, c, cfg, flags)
 
-    if seed_axis:
-        env, metrics = jax.vmap(
-            jax.vmap(apply_fn, in_axes=(0, 0, 0, None, None)))(
-                env, sim, action.reshape(B, S), rw_pages, ctx)
-    else:
-        env, metrics = jax.vmap(apply_fn)(env, sim, action, rw_pages, ctx)
+    env, metrics = jax.vmap(
+        jax.vmap(apply_fn, in_axes=(0, 0, 0, None, None)))(
+            env, sim, action.reshape(B, S), rw_pages, ctx)
     return env, agent, metrics
 
 
 def scan_epochs(trace, rw_pages, env, agent, tom_cands, ctx, cfg, spec,
                 agent_cfg, n_epochs, flags, agent_gate="cond",
-                tom_gate="cond", seed_axis=False):
+                tom_gate="cond"):
     """Un-jitted batched epoch scan shared by the serial and sweep runners.
-    All lane-shaped arguments carry a leading (B,) axis (env/agent a (B, S)
-    seed grid when `seed_axis` — see _epoch_batched); metrics come back as
-    (n_epochs, B[, S])."""
+    Trace, rw_pages and ctx carry a leading lane axis (B,), the env a
+    (B, S) seed grid and the agent flat (B*S,) cells (see _epoch_batched);
+    metrics come back as (n_epochs, B, S)."""
     def body(carry, _):
         env, agent = carry
         env, agent, m = _epoch_batched(env, agent, trace, rw_pages, tom_cands,
                                        ctx, cfg, spec, agent_cfg, flags,
-                                       agent_gate, tom_gate, seed_axis)
+                                       agent_gate, tom_gate)
         return (env, agent), m
 
     (env, agent), ms = jax.lax.scan(body, (env, agent), None, length=n_epochs)
@@ -1148,8 +1144,8 @@ def run_episode(trace: Trace, cfg: NMPConfig = NMPConfig(),
     checkpointing) lives one layer up in `nmp.continual.PolicyStore` — the
     engine only ever sees an AgentState in, an AgentState out.
 
-    This serial runner is the batched engine at batch size 1 (one vmapped
-    lane), so its numbers are bit-identical to the same lane inside a
+    This serial runner is the batched engine on a 1 x 1 (lane, seed) grid,
+    so its numbers are bit-identical to the same lane inside a
     `sweep.run_grid` batch by construction.
     """
     assert mapper in MAPPERS and technique in baselines.TECHNIQUES
@@ -1165,7 +1161,8 @@ def run_episode(trace: Trace, cfg: NMPConfig = NMPConfig(),
     tr = _batch1(pad_trace_ops(trace, trace.n_ops, cfg))
     rw = _batch1(jnp.asarray(trace.read_write))
     pt = page_table if page_table is not None else default_alloc(trace.n_pages, cfg)
-    env = _batch1(_init_env(pt, cfg, spec, seed, phase_ring_len(trace, cfg)))
+    env = _batch1(_batch1(_init_env(pt, cfg, spec, seed,
+                                    phase_ring_len(trace, cfg))))   # 1 x 1 grid
     tom_cands = baselines.tom_candidates(trace.n_pages, cfg)
     ctx = _batch1(make_ctx(trace, cfg, technique, mapper, forced_action,
                            explore))
@@ -1174,8 +1171,8 @@ def run_episode(trace: Trace, cfg: NMPConfig = NMPConfig(),
                                    _batch1(agent) if flags.has_agent else None,
                                    tom_cands, ctx, cfg, spec, agent_cfg,
                                    n_epochs, flags, agent_gate, tom_gate)
-    env = jax.tree.map(lambda a: a[0], env)
-    ms = {k: v[:, 0] for k, v in ms.items()}
+    env = jax.tree.map(lambda a: a[0, 0], env)
+    ms = {k: v[:, 0, 0] for k, v in ms.items()}
     if flags.has_agent:
         agent_out = jax.tree.map(lambda a: a[0], agent_out)
     else:

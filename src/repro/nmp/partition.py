@@ -107,11 +107,10 @@ def host_fetch(tree):
     shards, so they are gathered across processes first — every host gets
     the full result, keeping the unfold/write-back logic host-agnostic.
 
-    Note: the CPU backend (jax 0.4.37) cannot *execute* multiprocess
-    computations ("Multiprocess computations aren't implemented on the CPU
-    backend"), so on CPU the distributed path is exercised up to
-    process-group init and global device visibility only — end-to-end
-    multi-host dispatch needs a GPU/TPU backend."""
+    Note: the tests exercise the distributed path on the CPU backend only
+    up to process-group init and global device visibility; no
+    cross-process computation has been executed through it on any
+    backend."""
     if tree is None:
         return None
     if jax.process_count() > 1:

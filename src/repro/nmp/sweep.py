@@ -174,7 +174,7 @@ def _run_sweep(batch, tom_cands, cfg, spec, agent_cfg, n_epochs, n_episodes,
         env = init_envs(batch["page_table"], seeds)
         env, agent2, ms = scan_epochs(trace, batch["rw"], env, agent,
                                       tom_cands, ctx, cfg, spec, agent_cfg,
-                                      n_epochs, flags, seed_axis=True)
+                                      n_epochs, flags)
         out = {
             "cycles": env.cycles, "ops": env.ops_done,
             "hops_sum": env.hops_sum, "util_sum": env.util_sum,
@@ -511,10 +511,7 @@ def compiled_sweep_programs() -> int:
 
     The serving layer's steady-state guarantee is that this stays constant
     across service ticks once the slot programs are warm."""
-    try:
-        return int(_run_sweep._cache_size())
-    except AttributeError:                     # pragma: no cover - jax API
-        return 0
+    return int(_run_sweep._cache_size())
 
 
 def run_grid(scenarios: Sequence[Scenario], cfg: NMPConfig = NMPConfig(),

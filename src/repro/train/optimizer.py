@@ -58,7 +58,10 @@ def adamw(
     eps: float = 1e-8,
     weight_decay: float = 0.0,
     grad_clip: float = 0.0,
+    leaf_sum: Callable[[jnp.ndarray], jnp.ndarray] = jnp.sum,
 ) -> Optimizer:
+    """`leaf_sum` reduces each squared gradient leaf for the clip's global
+    norm (see `global_norm`)."""
     sched = lr if callable(lr) else constant_schedule(lr)
 
     def init(params):
@@ -68,7 +71,7 @@ def adamw(
     def update(grads, state, params, step):
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         if grad_clip > 0:
-            gnorm = global_norm(grads)
+            gnorm = global_norm(grads, leaf_sum)
             scale = jnp.minimum(1.0, grad_clip / (gnorm + 1e-9))
             grads = jax.tree.map(lambda g: g * scale, grads)
         t = step.astype(jnp.float32) + 1.0
@@ -230,6 +233,9 @@ def sgd(lr: float = 1e-2) -> Optimizer:
     return Optimizer(init, update)
 
 
-def global_norm(tree: PyTree) -> jnp.ndarray:
+def global_norm(tree: PyTree, leaf_sum: Callable[[jnp.ndarray], jnp.ndarray]
+                = jnp.sum) -> jnp.ndarray:
+    """L2 norm over every leaf: `leaf_sum` of each squared leaf, added in
+    leaf order."""
     leaves = jax.tree.leaves(tree)
-    return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves))
+    return jnp.sqrt(sum(leaf_sum(jnp.square(l.astype(jnp.float32))) for l in leaves))

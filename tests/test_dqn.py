@@ -75,60 +75,6 @@ def test_agent_learns_contextual_bandit():
     assert late.mean() > 0.7, late.mean()
 
 
-def test_q_values_infer_backends_agree():
-    """The fused Pallas dueling kernel (interpret mode on CPU) and the plain
-    jnp path must agree for both the single-state (act) and batched (TD
-    target) shapes the engine uses."""
-    cfg = DQNConfig(state_dim=106, n_actions=8)
-    params = dqn.init_params(jax.random.PRNGKey(0), cfg)
-    for shape in ((106,), (64, 106)):
-        s = jax.random.normal(jax.random.PRNGKey(1), shape)
-        ref = dqn.q_values_infer(params, s, cfg, backend="jnp")
-        pal = dqn.q_values_infer(params, s, cfg, backend="pallas")
-        assert pal.shape == ref.shape
-        np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(
-        np.asarray(dqn.q_values_infer(params, s, cfg, backend="jnp")),
-        np.asarray(dqn.q_values(params, s, cfg)))
-
-
-def test_q_values_infer_falls_back_off_fused_shape():
-    """Non-dueling or deeper nets are outside the fused kernel's shape family
-    and must silently use the jnp path."""
-    cfg = DQNConfig(state_dim=12, n_actions=4, hidden=(32, 32, 32))
-    params = dqn.init_params(jax.random.PRNGKey(0), cfg)
-    assert not dqn.fused_kernel_compatible(params)
-    s = jax.random.normal(jax.random.PRNGKey(1), (5, 12))
-    np.testing.assert_array_equal(
-        np.asarray(dqn.q_values_infer(params, s, cfg, backend="pallas")),
-        np.asarray(dqn.q_values(params, s, cfg)))
-
-
-def test_qnet_backend_env_var_validated(monkeypatch):
-    """An unknown REPRO_QNET_BACKEND must raise a clear error, not silently
-    fall back to the jnp path."""
-    monkeypatch.setenv("REPRO_QNET_BACKEND", "cuda")
-    with pytest.raises(ValueError, match="REPRO_QNET_BACKEND.*cuda"):
-        dqn._infer_backend()
-    for ok in dqn.QNET_BACKENDS:
-        monkeypatch.setenv("REPRO_QNET_BACKEND", ok)
-        assert dqn._infer_backend() in ("pallas", "jnp")
-
-
-def test_qnet_backend_argument_validated():
-    cfg = DQNConfig(state_dim=8, n_actions=4)
-    params = dqn.init_params(jax.random.PRNGKey(0), cfg)
-    s = jnp.zeros((2, 8))
-    with pytest.raises(ValueError, match="backend='tpu'"):
-        dqn.q_values_infer(params, s, cfg, backend="tpu")
-    # explicit "auto" resolves like the env default instead of silently
-    # skipping the kernel because it isn't literally "pallas"
-    np.testing.assert_array_equal(
-        np.asarray(dqn.q_values_infer(params, s, cfg, backend="auto")),
-        np.asarray(dqn.q_values_infer(params, s, cfg)))
-
-
 def test_train_step_noop_until_replay_ready():
     """Pre-`min_replay` the TD step must be an exact no-op (this is what lets
     the engine skip it under lax.cond)."""
@@ -156,3 +102,97 @@ def test_target_sync_periodic():
     d = sum(float(jnp.abs(a - b).sum()) for a, b in
             zip(jax.tree.leaves(ag.params), jax.tree.leaves(ag.target_params)))
     assert d == 0.0
+
+
+def test_order_fixed_layers_match_xla_and_their_gradients():
+    """`dense`, `dueling_head` and `tree_sum` compute what matmul, the
+    dueling mean and sum compute (to rounding), gradients included: their
+    custom VJPs must be the true derivatives."""
+    k = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(k[0], (5, 7))
+    w = jax.random.normal(k[1], (7, 3))
+    b = jax.random.normal(k[2], (3,))
+    g = jax.random.normal(k[3], (5, 3))
+
+    def ours(x, w, b):
+        z = dqn.dense(x, w, b)
+        return jnp.vdot(dqn.dueling_head(z[:, :1], z), g) + dqn.tree_sum(x, 0)[1]
+
+    def ref(x, w, b):
+        z = x @ w + b
+        q = z[:, :1] + z - jnp.mean(z, axis=-1, keepdims=True)
+        return jnp.vdot(q, g) + jnp.sum(x, 0)[1]
+
+    np.testing.assert_allclose(ours(x, w, b), ref(x, w, b), rtol=1e-5)
+    got = jax.grad(ours, argnums=(0, 1, 2))(x, w, b)
+    want = jax.grad(ref, argnums=(0, 1, 2))(x, w, b)
+    for a, e in zip(got, want):
+        np.testing.assert_allclose(a, e, rtol=1e-5, atol=1e-6)
+
+
+def test_td_step_bits_do_not_depend_on_batch_width():
+    """A cell's TD step (loss, gradients, clip, Adam) gives the same bits
+    vmapped alone and among other cells: the serial reference and the
+    batched sweep must learn identical agents."""
+    cfg = AgentConfig(dqn=DQNConfig(state_dim=10, n_actions=4), min_replay=4)
+
+    def trained(seed):
+        ag = A.cold_start(seed, cfg)
+        for i in range(12):
+            k = jax.random.fold_in(jax.random.PRNGKey(seed + 100), i)
+            ks = jax.random.split(k, 3)
+            ag = A.observe(ag, jax.random.normal(ks[0], (10,)), i % 4,
+                           jax.random.normal(ks[1], ()),
+                           jax.random.normal(ks[2], (10,)))
+        return A.train_step(ag, cfg, jax.random.PRNGKey(seed + 7)).params
+
+    f = jax.jit(jax.vmap(trained))
+    wide = f(jnp.arange(8))
+    for j in (0, 5):
+        alone = f(jnp.arange(j, j + 1))
+        for a, b in zip(jax.tree.leaves(wide), jax.tree.leaves(alone)):
+            np.testing.assert_array_equal(np.asarray(a)[j], np.asarray(b)[0])
+
+
+@pytest.mark.parametrize("width", [1, 2, 7, 21])
+def test_act_q_bits_do_not_depend_on_batch_width(width):
+    """The act path's Q-values (one state per cell, paper widths) give the
+    same bits for a cell vmapped among `width` cells as among 27, with the
+    weights entering the program as inputs, as the sweep's scan carry holds
+    them."""
+    from repro.configs.aimm_nmp import PAPER_4X4
+    from repro.nmp.engine import default_agent_cfg
+    cfg = default_agent_cfg(PAPER_4X4)
+    seeds = jnp.arange(27)
+    params = jax.vmap(lambda s: A.cold_start(s, cfg).params)(seeds)
+    states = jax.random.normal(jax.random.PRNGKey(3),
+                               (27, cfg.dqn.state_dim))
+    f = jax.jit(jax.vmap(lambda p, s: dqn.q_values(p, s, cfg.dqn)))
+    wide = np.asarray(f(params, states))
+    for j in (0, 27 - width):
+        cut = lambda t: jax.tree.map(lambda a: a[j:j + width], t)
+        np.testing.assert_array_equal(np.asarray(f(cut(params), cut(states))),
+                                      wide[j:j + width])
+
+
+@pytest.mark.parametrize("leaf_sum", ["jnp", "tree"])
+def test_adamw_clip_bounds_global_norm(leaf_sum):
+    """`adamw`'s clip scales gradients to `grad_clip` global norm whichever
+    per-leaf sum it is given (the agent passes `dqn.tree_sum`)."""
+    from repro.train.optimizer import adamw, global_norm
+    fn = jnp.sum if leaf_sum == "jnp" else (
+        lambda g: dqn.tree_sum(g.reshape(-1), 0))
+    k = jax.random.split(jax.random.PRNGKey(4), 2)
+    grads = {"w": 10.0 * jax.random.normal(k[0], (37, 5)),
+             "b": 10.0 * jax.random.normal(k[1], (5,))}
+    np.testing.assert_allclose(global_norm(grads, fn), global_norm(grads),
+                               rtol=1e-6)
+    params = jax.tree.map(jnp.zeros_like, grads)
+    opt = adamw(1.0, grad_clip=0.5, leaf_sum=fn)
+    _, st = opt.update(grads, opt.init(params), params, jnp.int32(0))
+    # Adam's first moment holds (1 - b1) * clipped grads.
+    np.testing.assert_allclose(global_norm(st["m"]), 0.1 * 0.5, rtol=1e-5)
+    unclipped = adamw(1.0).update(grads, opt.init(params), params,
+                                  jnp.int32(0))[1]
+    np.testing.assert_allclose(global_norm(unclipped["m"]),
+                               0.1 * global_norm(grads), rtol=1e-5)

@@ -196,12 +196,10 @@ _DIST_SCRIPT = textwrap.dedent("""
 @pytest.mark.slow
 def test_distributed_init_two_local_processes(tmp_path):
     """Two local processes join one jax.distributed group and see a 4-device
-    global platform (2 forced host devices each).  The CPU backend cannot
-    *execute* cross-process computations (jax 0.4.37 raises
-    "Multiprocess computations aren't implemented on the CPU backend"), so
-    this exercises exactly what the scaffolding claims: process-group init,
-    global device visibility, and graceful single-host degradation when the
-    knobs are unset."""
+    global platform (2 forced host devices each).  No cross-process
+    computation is dispatched, so this exercises exactly what the
+    scaffolding claims: process-group init, global device visibility, and
+    graceful single-host degradation when the knobs are unset."""
     base = dict(
         os.environ,
         XLA_FLAGS=("--xla_force_host_platform_device_count=2 "

@@ -16,7 +16,7 @@ against the pure-jnp paths and the engine goldens:
     trace window;
   * the dueling-qnet forward kernel in interpret mode vs its jnp oracle;
   * the backend knobs' fail-fast validation (REPRO_EPOCH_BACKEND,
-    REPRO_SWEEP_LAND, REPRO_STORE_STAGING) and the auto->jnp CPU default.
+    REPRO_SWEEP_LAND, REPRO_STORE_STAGING) and the auto->jnp default.
 
 The engine reads the knob through `BodyFlags.epoch_backend` — a static jit
 argument — so monkeypatching the env var between calls genuinely selects a
@@ -206,11 +206,11 @@ def test_epoch_backend_knob_validates(monkeypatch):
 
 
 def test_epoch_backend_auto_is_jnp_on_cpu(monkeypatch):
-    import jax
+    # `auto` is jnp on every platform: the TPU compiler refuses the fused
+    # kernel (kernels/epoch_fused/kernel.py)
     monkeypatch.delenv(epoch_ops.ENV_KNOB, raising=False)
-    expect = "pallas" if jax.default_backend() == "tpu" else "jnp"
-    assert resolve_backend() == expect
-    assert resolve_backend("auto") == expect
+    assert resolve_backend() == "jnp"
+    assert resolve_backend("auto") == "jnp"
 
 
 def test_sweep_knobs_validate(monkeypatch):

@@ -14,10 +14,9 @@ from repro.sharding import policies
 
 
 def _mesh(multi):
-    # jax >= 0.4.36 AbstractMesh takes ((name, size), ...) pairs
     if multi:
-        return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-    return AbstractMesh((("data", 16), ("model", 16)))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _axis_size(mesh, axis):
