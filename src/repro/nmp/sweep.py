@@ -90,6 +90,7 @@ import numpy as np
 from repro.core import agent as agent_mod
 from repro.nmp import partition
 from repro.nmp import plan as plan_mod
+from repro.nmp import spans
 from repro.nmp.config import NMPConfig
 from repro.nmp.engine import (TraceCtx, _init_env, default_agent_cfg,
                               scan_epochs, state_spec_for)
@@ -406,7 +407,8 @@ def _warm_agent_batch(group, n_lanes_padded: int, store, agent_cfg,
 
 
 def prepare_group_batch(plan: GridPlan, group, group_cfg: NMPConfig, mesh,
-                        n_lanes: int | None = None, host_cache=None):
+                        n_lanes: int | None = None, host_cache=None,
+                        ids: dict | None = None):
     """Host-side build + device placement of one group's input batch.
 
     `n_lanes` forces the padded lane count (the serving layer's fixed slot
@@ -419,7 +421,9 @@ def prepare_group_batch(plan: GridPlan, group, group_cfg: NMPConfig, mesh,
     off `batch["ep_seed"].shape[1]` (shape metadata stays readable after the
     batch is donated).  The host->device transfer happens here, so a caller
     can overlap it with a previously dispatched compiled call (double
-    buffering)."""
+    buffering).  `ids` (`call`, `group`) tag the `build` and `place` host
+    spans (`nmp.spans`)."""
+    ids = ids or {}
     n_lanes_padded = (partition.padded_lane_count(group.n_lanes, mesh)
                       if n_lanes is None else n_lanes)
     if n_lanes_padded < group.n_lanes:
@@ -428,12 +432,16 @@ def prepare_group_batch(plan: GridPlan, group, group_cfg: NMPConfig, mesh,
     if n_lanes_padded != partition.padded_lane_count(n_lanes_padded, mesh):
         raise ValueError(f"n_lanes={n_lanes_padded} is not divisible by the "
                          "device mesh width")
-    batch_np = plan_mod.build_group_batch(plan, group, group_cfg,
-                                          host_cache=host_cache)
-    batch_np = partition.pad_seed_axis(
-        batch_np, partition.padded_seed_count(group.n_seeds, mesh))
-    batch_np = partition.pad_group_batch(batch_np, n_lanes_padded)
-    return partition.shard_group_batch(batch_np, mesh), n_lanes_padded
+    n_seeds_padded = partition.padded_seed_count(group.n_seeds, mesh)
+    with spans.span("build", **ids, lanes=group.n_lanes,
+                    lanes_padded=n_lanes_padded, seeds_padded=n_seeds_padded):
+        batch_np = plan_mod.build_group_batch(plan, group, group_cfg,
+                                              host_cache=host_cache)
+        batch_np = partition.pad_seed_axis(batch_np, n_seeds_padded)
+        batch_np = partition.pad_group_batch(batch_np, n_lanes_padded)
+    with spans.span("place", **ids, h2d_bytes=spans.nbytes(batch_np)):
+        batch = partition.shard_group_batch(batch_np, mesh)
+    return batch, n_lanes_padded
 
 
 def executed_flags(group, n_seeds: int):
@@ -449,14 +457,16 @@ def executed_flags(group, n_seeds: int):
 
 def dispatch_sweep(batch, tom_cands, group_cfg: NMPConfig, spec, agent_cfg,
                    n_epochs: int, n_episodes: int, ring_len: int, flags,
-                   warm_agent=None, want_agent: bool = False):
+                   warm_agent=None, want_agent: bool = False,
+                   ids: dict | None = None):
     """Dispatch the compiled sweep for one prepared group batch.
 
     The call is asynchronous: the returned (outs, final env, final agent)
     leaves are unmaterialized jax arrays — block (`jax.block_until_ready`)
     when the values are needed, and build the *next* batch in between to
-    hide its host->device transfer behind the running program."""
-    with warnings.catch_warnings():
+    hide its host->device transfer behind the running program.  `ids`
+    (`call`, `group`) tag the `dispatch` host span (`nmp.spans`)."""
+    with warnings.catch_warnings(), spans.span("dispatch", **(ids or {})):
         # int trace/ctx buffers have no same-shaped outputs to reuse;
         # their donation being unusable is expected, not a leak.
         warnings.filterwarnings(
@@ -531,30 +541,40 @@ def run_grid(scenarios: Sequence[Scenario], cfg: NMPConfig = NMPConfig(),
     Returns a SweepResult whose per-cell `cycles`/`ops`/`opc` match the serial
     `run_episode`/`run_program` protocol bit-for-bit (see module docstring).
     """
-    scenarios = list(scenarios)
-    t0 = time.time()
-    plan = plan_grid(scenarios, cfg)
-    spec = state_spec_for(cfg)
-    agent_cfg = agent_cfg or default_agent_cfg(cfg)
-    devices = partition.sweep_devices()
-    shape = (partition.sweep_mesh_shape(len(devices))
-             or partition.auto_mesh_shape(
-                 len(devices), [(g.n_lanes, g.n_seeds, g.n_episodes)
-                                for g in plan.groups]))
-    mesh = partition.build_mesh(devices, shape)
-    tom_cands = partition.replicate(plan_mod.plan_tom_candidates(plan, cfg),
-                                    mesh)
-    if store is None and plan.lineage_tags():
-        from repro.nmp.continual import PolicyStore
-        store = PolicyStore()
+    call = spans.next_call()
+    with spans.span("run_grid", call=call) as root:
+        return _run_grid(list(scenarios), cfg, agent_cfg, store, call, root)
 
-    # Mixed-topology grids: the stacked final env needs one link-space
-    # width, so per-group pending link loads are padded to the widest
-    # topology's link count before stacking (padding links carry zero load).
-    from repro.nmp.topology import get_topology
-    n_links_max = max(
-        get_topology(dataclasses.replace(cfg, topology=t)).n_links
-        for t in dict.fromkeys(plan.topologies))
+
+def _run_grid(scenarios, cfg, agent_cfg, store, call, root):
+    """`run_grid`'s body inside its root host span `root`, each phase in a
+    span of its own (`nmp.spans`)."""
+    t0 = time.time()
+    with spans.span("plan", call=call):
+        plan = plan_grid(scenarios, cfg)
+        spec = state_spec_for(cfg)
+        agent_cfg = agent_cfg or default_agent_cfg(cfg)
+        devices = partition.sweep_devices()
+        shape = (partition.sweep_mesh_shape(len(devices))
+                 or partition.auto_mesh_shape(
+                     len(devices), [(g.n_lanes, g.n_seeds, g.n_episodes)
+                                    for g in plan.groups]))
+        mesh = partition.build_mesh(devices, shape)
+        tom_cands = partition.replicate(
+            plan_mod.plan_tom_candidates(plan, cfg), mesh)
+        if store is None and plan.lineage_tags():
+            from repro.nmp.continual import PolicyStore
+            store = PolicyStore()
+
+        # Mixed-topology grids: the stacked final env needs one link-space
+        # width, so per-group pending link loads are padded to the widest
+        # topology's link count before stacking (padding links carry zero
+        # load).
+        from repro.nmp.topology import get_topology
+        n_links_max = max(
+            get_topology(dataclasses.replace(cfg, topology=t)).n_links
+            for t in dict.fromkeys(plan.topologies))
+    root.set_metadata(lanes=plan.n_lanes, groups=len(plan.groups))
 
     outs: list = [None] * len(scenarios)
     envs: list = [None] * len(scenarios)
@@ -565,61 +585,72 @@ def run_grid(scenarios: Sequence[Scenario], cfg: NMPConfig = NMPConfig(),
     # the lock only keeps the registry's dict/LRU bookkeeping atomic.
     store_lock = threading.Lock()
 
-    def launch(group):
+    def launch(gi):
         """Host batch build + async dispatch of one group's program."""
+        group = plan.groups[gi]
+        ids = {"call": call, "group": gi}
         group_cfg = dataclasses.replace(cfg, topology=group.topology)
         batch, n_lanes_padded = prepare_group_batch(plan, group, group_cfg,
-                                                    mesh)
+                                                    mesh, ids=ids)
         s_pad = int(batch["ep_seed"].shape[1])
         if group.lineage:
-            with store_lock:
+            with store_lock, spans.span("place", **ids) as place:
                 warm = _warm_agent_batch(group, n_lanes_padded, store,
                                          agent_cfg, n_seeds=s_pad, mesh=mesh,
                                          staging=staging)
+                place.set_metadata(h2d_bytes=spans.nbytes(warm))
         else:
             warm = None
         out, env_fin, agent_fin = dispatch_sweep(
             batch, tom_cands, group_cfg, spec, agent_cfg, plan.n_epochs,
             group.n_episodes, plan.ring_len, executed_flags(group, s_pad),
-            warm_agent=warm, want_agent=group.lineage)
-        return group, group_cfg, s_pad, out, env_fin, agent_fin
+            warm_agent=warm, want_agent=group.lineage, ids=ids)
+        return ids, group, group_cfg, s_pad, out, env_fin, agent_fin
 
     def land(state):
         """Block on a dispatched group, fetch to host, unfold its lanes."""
-        group, group_cfg, s_pad, out, env_fin, agent_fin = state
-        out = partition.host_fetch(jax.block_until_ready(out))
-        env_fin = partition.host_fetch(env_fin)
-        pad_l = n_links_max - get_topology(group_cfg).n_links
-        if pad_l:
-            env_fin = env_fin._replace(pending_mig_loads=np.pad(
-                env_fin.pending_mig_loads, [(0, 0)] * 2 + [(0, pad_l)]))
-        pad_e = plan.n_episodes - group.n_episodes
-        for li, lane in enumerate(group.lanes):
-            cells = {}               # seed slot -> unfolded metric dict
-            for i, si in zip(lane.indices, lane.slots):
-                if si not in cells:
-                    cells[si] = (
-                        {k: np.pad(np.asarray(v[li, si]),
-                                   [(0, pad_e)] + [(0, 0)]
-                                   * (v[li, si].ndim - 1))
-                         for k, v in out.items()},
-                        jax.tree.map(
-                            lambda a, li=li, si=si: np.asarray(a[li, si]),
-                            env_fin))
-                outs[i], envs[i] = cells[si]
-        if group.lineage:
-            # Hand every tag's final agent back to the store.  When several
-            # cells share a tag (seed replicas, repeated tags), the lineage
-            # continues from the first cell of the last lane declaring it.
-            agent_fin = partition.host_fetch(agent_fin)
-            with store_lock:
-                for li, lane in enumerate(group.lanes):
-                    cell = jax.tree.map(
-                        lambda a, li=li, s=lane.slots[0]:
-                            np.asarray(a[li * s_pad + s]),
-                        agent_fin)
-                    store.put(lane.scenario.lineage, cell,
-                              scenario=lane.scenario.name)
+        ids, group, group_cfg, s_pad, out, env_fin, agent_fin = state
+        with spans.span("wait", **ids):
+            out = jax.block_until_ready(out)
+        with spans.span("fetch", **ids,
+                        d2h_bytes=spans.nbytes((out, env_fin))):
+            out = partition.host_fetch(out)
+            env_fin = partition.host_fetch(env_fin)
+        with spans.span("unfold", **ids, lanes=group.n_lanes):
+            pad_l = n_links_max - get_topology(group_cfg).n_links
+            if pad_l:
+                env_fin = env_fin._replace(pending_mig_loads=np.pad(
+                    env_fin.pending_mig_loads, [(0, 0)] * 2 + [(0, pad_l)]))
+            pad_e = plan.n_episodes - group.n_episodes
+            for li, lane in enumerate(group.lanes):
+                cells = {}               # seed slot -> unfolded metric dict
+                for i, si in zip(lane.indices, lane.slots):
+                    if si not in cells:
+                        cells[si] = (
+                            {k: np.pad(np.asarray(v[li, si]),
+                                       [(0, pad_e)] + [(0, 0)]
+                                       * (v[li, si].ndim - 1))
+                             for k, v in out.items()},
+                            jax.tree.map(
+                                lambda a, li=li, si=si: np.asarray(a[li, si]),
+                                env_fin))
+                    outs[i], envs[i] = cells[si]
+            if group.lineage:
+                # Hand every tag's final agent back to the store.  When
+                # several cells share a tag (seed replicas, repeated tags),
+                # the lineage continues from the first cell of the last lane
+                # declaring it.
+                with spans.span("fetch", **ids,
+                                d2h_bytes=spans.nbytes(agent_fin)):
+                    agent_fin = partition.host_fetch(agent_fin)
+                with store_lock:
+                    for li, lane in enumerate(group.lanes):
+                        cell = jax.tree.map(
+                            lambda a, li=li, s=lane.slots[0]:
+                                np.asarray(a[li * s_pad + s]),
+                            agent_fin)
+                        store.put(lane.scenario.lineage, cell,
+                                  scenario=lane.scenario.name)
 
     # Heaviest group first; one group in flight while the next group's host
     # batch is built, and — under async landing (REPRO_SWEEP_LAND, the
@@ -639,7 +670,7 @@ def run_grid(scenarios: Sequence[Scenario], cfg: NMPConfig = NMPConfig(),
         for gi in plan_mod.packed_group_order(plan,
                                               partition.mesh_lane_dim(mesh),
                                               partition.mesh_seed_dim(mesh)):
-            launched = launch(plan.groups[gi])
+            launched = launch(gi)
             if pending is not None:
                 if pool is not None:
                     landings.append(pool.submit(land, pending))
@@ -657,15 +688,16 @@ def run_grid(scenarios: Sequence[Scenario], cfg: NMPConfig = NMPConfig(),
         if pool is not None:
             pool.shutdown(wait=True)
 
-    metrics = {k: np.stack([o[k] for o in outs]) for k in outs[0]}
-    final_env = jax.tree.map(lambda *xs: np.stack(xs), *envs)
-    desc = partition.mesh_desc(mesh)
-    return SweepResult(scenarios=scenarios, cfg=cfg, metrics=metrics,
-                       final_env=final_env, n_episodes=plan.n_episodes,
-                       wall_s=time.time() - t0, plan=plan,
-                       n_devices=desc["n_devices"],
-                       mesh_shape=tuple(desc["shape"]),
-                       store=store)
+    with spans.span("stack", call=call):
+        metrics = {k: np.stack([o[k] for o in outs]) for k in outs[0]}
+        final_env = jax.tree.map(lambda *xs: np.stack(xs), *envs)
+        desc = partition.mesh_desc(mesh)
+        return SweepResult(scenarios=scenarios, cfg=cfg, metrics=metrics,
+                           final_env=final_env, n_episodes=plan.n_episodes,
+                           wall_s=time.time() - t0, plan=plan,
+                           n_devices=desc["n_devices"],
+                           mesh_shape=tuple(desc["shape"]),
+                           store=store)
 
 
 def run_grid_serial(scenarios: Sequence[Scenario],
