@@ -191,19 +191,29 @@ def phase_ring_len(trace: Trace, cfg: NMPConfig) -> int:
     return int(np.clip(iter_ops // cfg.epoch_ops, 1, n_epochs + 1))
 
 
-def make_ctx(trace: Trace, cfg: NMPConfig, technique: str, mapper: str,
-             forced_action: int = -1, explore: bool = True) -> TraceCtx:
+def make_ctx_host(trace: Trace, cfg: NMPConfig, technique: str, mapper: str,
+                  forced_action: int = -1, explore: bool = True) -> TraceCtx:
+    """`TraceCtx` of numpy scalars (np.int32 / np.bool_), built on the host
+    alone: the batched sweep stacks these into its input batch without a
+    device round trip per field."""
     assert mapper in MAPPERS and technique in baselines.TECHNIQUES
     return TraceCtx(
-        n_ops=jnp.asarray(trace.n_ops, jnp.int32),
-        n_pages=jnp.asarray(trace.n_pages, jnp.int32),
-        t_ring=jnp.asarray(phase_ring_len(trace, cfg), jnp.int32),
-        pei_idx=jnp.asarray(pei_hot_index(trace.n_pages, cfg), jnp.int32),
-        technique=jnp.asarray(TECH_ID[technique], jnp.int32),
-        mapper=jnp.asarray(MAPPER_ID[mapper], jnp.int32),
-        forced_action=jnp.asarray(forced_action, jnp.int32),
-        explore=jnp.asarray(explore, bool),
+        n_ops=np.int32(trace.n_ops),
+        n_pages=np.int32(trace.n_pages),
+        t_ring=np.int32(phase_ring_len(trace, cfg)),
+        pei_idx=np.int32(pei_hot_index(trace.n_pages, cfg)),
+        technique=np.int32(TECH_ID[technique]),
+        mapper=np.int32(MAPPER_ID[mapper]),
+        forced_action=np.int32(forced_action),
+        explore=np.bool_(explore),
     )
+
+
+def make_ctx(trace: Trace, cfg: NMPConfig, technique: str, mapper: str,
+             forced_action: int = -1, explore: bool = True) -> TraceCtx:
+    """`make_ctx_host` as device arrays (the serial runner's context)."""
+    return jax.tree.map(jnp.asarray, make_ctx_host(
+        trace, cfg, technique, mapper, forced_action, explore))
 
 
 class EnvState(NamedTuple):
@@ -1115,11 +1125,18 @@ def default_agent_cfg(cfg: NMPConfig) -> AgentConfig:
                                      gamma=0.0))
 
 
-def pad_trace_ops(trace: Trace, n_total: int, cfg: NMPConfig) -> dict:
-    """Trace op arrays padded to `n_total + w_max` (dict of jnp arrays)."""
+def pad_trace_ops_host(trace: Trace, n_total: int,
+                       cfg: NMPConfig) -> dict[str, np.ndarray]:
+    """Trace op arrays padded to `n_total + w_max` (dict of numpy arrays)."""
     pad = n_total - trace.n_ops + cfg.w_max
-    return {k: jnp.asarray(np.concatenate([v, np.zeros(pad, v.dtype)]))
+    return {k: np.concatenate([v, np.zeros(pad, v.dtype)])
             for k, v in trace.as_dict().items() if k != "program_id"}
+
+
+def pad_trace_ops(trace: Trace, n_total: int, cfg: NMPConfig) -> dict:
+    """`pad_trace_ops_host` as device arrays (dict of jnp arrays)."""
+    return {k: jnp.asarray(v)
+            for k, v in pad_trace_ops_host(trace, n_total, cfg).items()}
 
 
 def _batch1(tree):

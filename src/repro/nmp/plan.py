@@ -41,8 +41,8 @@ import numpy as np
 from repro.kernels.epoch_fused import ops as epoch_ops
 from repro.nmp import baselines
 from repro.nmp.config import NMPConfig
-from repro.nmp.engine import (BodyFlags, make_ctx, pad_trace_ops, pei_top_k,
-                              phase_ring_len, serial_epochs)
+from repro.nmp.engine import (BodyFlags, make_ctx_host, pad_trace_ops_host,
+                              pei_top_k, phase_ring_len, serial_epochs)
 from repro.nmp.paging import default_alloc
 from repro.nmp.scenarios import Scenario
 
@@ -439,6 +439,10 @@ def build_group_batch(plan: GridPlan, group: GroupPlan, cfg: NMPConfig,
                       host_cache: dict | None = None) -> dict[str, np.ndarray]:
     """Materialize one group's input batch as numpy arrays.
 
+    Host NumPy only: no device array is made or read back (on a chip host
+    each small synchronous transfer costs a fraction of a millisecond, and
+    a lane would need about twenty).
+
     Trace/ctx/page-table entries carry the lane axis (L, ...); the episode
     seed schedule carries the folded seed axis as (L, S, E) with the
     per-lane exploration schedule at (L, E) — seed replicas of a lane share
@@ -460,8 +464,7 @@ def build_group_batch(plan: GridPlan, group: GroupPlan, cfg: NMPConfig,
             lanes.append(host_cache[key])
             continue
         tr = sc.trace
-        ops = {k: np.asarray(v) for k, v in
-               pad_trace_ops(tr, plan.n_ops_max, cfg).items()}
+        ops = pad_trace_ops_host(tr, plan.n_ops_max, cfg)
         pt = (np.asarray(sc.page_table, np.int32) if sc.page_table is not None
               else default_alloc(tr.n_pages, cfg))
         # pad the page table/RW flags with never-referenced filler pages that
@@ -470,16 +473,16 @@ def build_group_batch(plan: GridPlan, group: GroupPlan, cfg: NMPConfig,
         pt = np.concatenate([pt, pad_pages.astype(np.int32)])
         rw = np.concatenate([tr.read_write,
                              np.zeros(plan.n_pages_max - tr.n_pages, bool)])
-        ctx = make_ctx(tr, cfg, sc.technique, sc.mapper, sc.forced_action)
+        ctx = make_ctx_host(tr, cfg, sc.technique, sc.mapper,
+                            sc.forced_action)
         scheds = [episode_schedule(sc, seed, group.n_episodes)
                   for seed in lane.seeds]
         built = {
             **ops, "page_table": pt, "rw": rw,
-            "n_ops": np.int32(ctx.n_ops), "n_pages": np.int32(ctx.n_pages),
-            "t_ring": np.int32(ctx.t_ring), "pei_idx": np.int32(ctx.pei_idx),
-            "technique": np.int32(ctx.technique),
-            "mapper": np.int32(ctx.mapper),
-            "forced_action": np.int32(ctx.forced_action),
+            "n_ops": ctx.n_ops, "n_pages": ctx.n_pages,
+            "t_ring": ctx.t_ring, "pei_idx": ctx.pei_idx,
+            "technique": ctx.technique, "mapper": ctx.mapper,
+            "forced_action": ctx.forced_action,
             "ep_seed": np.stack([s for s, _ in scheds]),       # (S, E)
             "ep_explore": scheds[0][1],                        # (E,)
         }

@@ -13,11 +13,13 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 
 from repro.nmp import NMPConfig, make_trace
-from repro.nmp import partition
+from repro.nmp import engine, partition
+from repro.nmp import plan as plan_mod
 from repro.nmp.plan import build_group_batch, plan_grid
 from repro.nmp.scenarios import Scenario, seed_variants
 
@@ -210,6 +212,89 @@ def test_envelope_dominance_and_forced_plan():
     cold = plan_grid([Scenario(name="s", trace=small, mapper="none")], CFG,
                      envelope=wide)
     assert cold.groups[0].n_episodes == 3
+
+
+# ---------------------------------------------------------------------------
+# Batch build
+# ---------------------------------------------------------------------------
+
+def _build_grid():
+    """Every mapper and technique, a given page table, footprints that need
+    page padding (KM 512 pages, RBM 96), seed replicas folded onto one lane,
+    an eval episode and a scripted (forced-action) lane."""
+    km = make_trace("KM", n_ops=384)
+    rbm = make_trace("RBM", n_ops=512)
+    grid = [Scenario(name=f"KM/{tech}/{mapper}", trace=km, technique=tech,
+                     mapper=mapper)
+            for tech in ("bnmp", "ldb", "pei") for mapper in ("none", "tom")]
+    pt = (np.arange(rbm.n_pages) * 5 % CFG.n_cubes).astype(np.int32)
+    grid.append(Scenario(name="RBM/pt", trace=rbm, technique="ldb",
+                         page_table=pt))
+    grid += seed_variants(Scenario(name="KM/aimm", trace=km, technique="pei",
+                                   mapper="aimm", episodes=2,
+                                   eval_episode=True), seeds=(0, 1, 2))
+    grid.append(Scenario(name="RBM/forced", trace=rbm, mapper="aimm",
+                         forced_action=2, episodes=2))
+    return grid
+
+
+def _device_round_trip_build(monkeypatch, plan, group):
+    """The batch built through the device wrappers (`pad_trace_ops`,
+    `make_ctx`) read back with `np.asarray`."""
+    with monkeypatch.context() as m:
+        m.setattr(plan_mod, "pad_trace_ops_host",
+                  lambda tr, n, cfg: {k: np.asarray(v) for k, v in
+                                      engine.pad_trace_ops(tr, n, cfg).items()})
+        m.setattr(plan_mod, "make_ctx_host",
+                  lambda *a: jax.tree.map(np.asarray, engine.make_ctx(*a)))
+        return build_group_batch(plan, group, CFG)
+
+
+def test_build_group_batch_makes_no_device_transfer(monkeypatch):
+    plan = plan_grid(_build_grid(), CFG)
+    assert len(plan.groups) == 2
+    assert plan.n_pages_max > min(sc.trace.n_pages for sc in plan.scenarios)
+    cache = {}
+    with jax.transfer_guard("disallow"):
+        for group in plan.groups:
+            build_group_batch(plan, group, CFG)
+            build_group_batch(plan, group, CFG, host_cache=cache)
+            build_group_batch(plan, group, CFG, host_cache=cache)   # hits
+        # the guard is live: the device wrappers trip it
+        with pytest.raises(Exception, match="Disallowed"):
+            _device_round_trip_build(monkeypatch, plan, plan.groups[0])
+    assert len(cache) == plan.n_lanes
+
+
+def test_build_group_batch_bit_identical_to_device_wrappers(monkeypatch):
+    plan = plan_grid(_build_grid(), CFG)
+    agent, det = plan.groups
+    assert agent.n_seeds == 3 and agent.n_episodes == 3    # 2 + eval
+    assert any(ln.scenario.forced_action >= 0 for ln in det.lanes)
+    for group in plan.groups:
+        got = build_group_batch(plan, group, CFG)
+        want = _device_round_trip_build(monkeypatch, plan, group)
+        assert got.keys() == want.keys()
+        for k in want:
+            assert type(got[k]) is np.ndarray, k
+            assert (got[k].dtype, got[k].shape) == (want[k].dtype,
+                                                    want[k].shape), k
+            assert got[k].tobytes() == want[k].tobytes(), k
+    # the serial runner's device context and trace ops carry the same values
+    for sc in plan.scenarios:
+        host = engine.make_ctx_host(sc.trace, CFG, sc.technique, sc.mapper,
+                                    sc.forced_action, explore=False)
+        dev = engine.make_ctx(sc.trace, CFG, sc.technique, sc.mapper,
+                              sc.forced_action, explore=False)
+        for name, h, d in zip(host._fields, host, dev):
+            assert isinstance(h, np.generic), name
+            assert (h.dtype, h) == (np.asarray(d).dtype, np.asarray(d)), name
+        ops = engine.pad_trace_ops_host(sc.trace, plan.n_ops_max, CFG)
+        for k, v in engine.pad_trace_ops(sc.trace, plan.n_ops_max,
+                                         CFG).items():
+            assert type(ops[k]) is np.ndarray
+            assert ops[k].dtype == v.dtype
+            np.testing.assert_array_equal(ops[k], np.asarray(v))
 
 
 # ---------------------------------------------------------------------------
