@@ -134,7 +134,7 @@ def phase_exactness(ctx):
     lanes = [names.index(n) for n in SERIAL_LANES]
     serial = run_grid_serial([grid[i] for i in lanes], cfg)
     diff = [n for n, i, s in zip(SERIAL_LANES, lanes, serial)
-            if s != res.episode_summary(i)]
+            if any(s[k] != v for k, v in res.episode_summary(i).items())]
     for n in diff:
         emit("exactness", serial_mismatch=n)
     emit("exactness", serial_lanes_bit_identical=f"{len(lanes) - len(diff)}"

@@ -4,7 +4,7 @@ The entire simulate -> observe -> act -> learn loop is a single `jax.lax.scan`
 (one step per agent invocation epoch), so an AIMM run is one compiled XLA
 program: the continual-learning agent literally trains inside the simulator.
 
-Epoch model (documented cost model; see DESIGN.md §2):
+Epoch model (the cost model every lane runs):
 
   window   : the next `window_sizes[interval_level]` ops of the trace
   schedule : technique (BNMP/LDB/PEI) picks a compute cube per op, then the
@@ -97,6 +97,10 @@ TECH_ID = {t: i for i, t in enumerate(baselines.TECHNIQUES)}
 # Energy counter layout (see stats.py).
 EN_PAGE_CACHE, EN_NMP_BUF, EN_MIG_Q, EN_MDMA, EN_WEIGHT, EN_REPLAY, \
     EN_STATE_BUF, EN_NET_BIT_HOPS, EN_MEM_BITS, EN_N = range(10)
+
+# `target` timeline value of an epoch that applied no data or compute remap
+# (a remap's target is a cube id 0..C-1, or C for "source mode").
+NO_TARGET = 255
 
 # TOM control period: K profiling windows (one per candidate) + this many
 # commit windows running the winner (shared by _epoch_sim's phase arithmetic
@@ -591,37 +595,40 @@ def _epoch_sim(env: EnvState, trace: dict, tom_cands: jnp.ndarray,
     # acted on in the last few invocations are skipped so invocations cover the
     # hot set instead of hammering one page.
     if flags.any_aimm:
-        touch_cnt = shared.touch_cnt
-        recently = jnp.zeros((P,)).at[env.recent_pages].set(
-            (env.recent_pages >= 0).astype(jnp.float32))
-        hot_page = jnp.argmax(touch_cnt * (1.0 - recently)).astype(jnp.int32)
-        touches_hot = touch_cnt[hot_page]
-        is_hot_op = ((dest == hot_page) | (src1 == hot_page)
-                     | (src2 == hot_page)) & (valid > 0)
-        first_hot = jnp.argmax(is_hot_op)
-        ccube_hot = ccube[first_hot]
-        hops_hot = hops_op[first_hot]
+        with jax.named_scope("aimm.select"):
+            touch_cnt = shared.touch_cnt
+            recently = jnp.zeros((P,)).at[env.recent_pages].set(
+                (env.recent_pages >= 0).astype(jnp.float32))
+            hot_page = jnp.argmax(touch_cnt * (1.0 - recently)).astype(
+                jnp.int32)
+            touches_hot = touch_cnt[hot_page]
+            is_hot_op = ((dest == hot_page) | (src1 == hot_page)
+                         | (src2 == hot_page)) & (valid > 0)
+            first_hot = jnp.argmax(is_hot_op)
+            ccube_hot = ccube[first_hot]
+            hops_hot = hops_op[first_hot]
 
-        cache, ent = lookup_or_insert(env.cache, hot_page)
-        cache = cache._replace(
-            freq=cache.freq.at[ent].add(1.0),
-            accesses=cache.accesses.at[ent].add(touches_hot),
-            hop_hist=push_hist(cache.hop_hist, ent, hops_hot),
-            lat_hist=push_hist(cache.lat_hist, ent, mean_lat),
-        )
-        env_rng, _k_agent, k_nbr = jax.random.split(env.rng, 3)
+            cache, ent = lookup_or_insert(env.cache, hot_page)
+            cache = cache._replace(
+                freq=cache.freq.at[ent].add(1.0),
+                accesses=cache.accesses.at[ent].add(touches_hot),
+                hop_hist=push_hist(cache.hop_hist, ent, hops_hot),
+                lat_hist=push_hist(cache.lat_hist, ent, mean_lat),
+            )
+            env_rng, _k_agent, k_nbr = jax.random.split(env.rng, 3)
 
-        # state vector (paper Fig. 3)
-        page_rate = touches_hot / jnp.maximum(3.0 * w_valid, 1.0)
-        mig_per_acc = cache.migrations[ent] / jnp.maximum(cache.accesses[ent],
-                                                          1.0)
-        svec = build_state(
-            spec, nmp_occ, rb_hit, mc_queue, env.global_act_hist,
-            env.interval_level, page_rate, mig_per_acc,
-            cache.hop_hist[ent], cache.lat_hist[ent], cache.mig_hist[ent],
-            cache.act_hist[ent], eff_table[hot_page], ccube_hot,
-            occ_norm=float(cfg.nmp_table_size),
-        )
+            # state vector (paper Fig. 3)
+            page_rate = touches_hot / jnp.maximum(3.0 * w_valid, 1.0)
+            mig_per_acc = (cache.migrations[ent]
+                           / jnp.maximum(cache.accesses[ent], 1.0))
+            svec = build_state(
+                spec, nmp_occ, rb_hit, mc_queue, env.global_act_hist,
+                env.interval_level, page_rate, mig_per_acc,
+                cache.hop_hist[ent], cache.lat_hist[ent],
+                cache.mig_hist[ent], cache.act_hist[ent],
+                eff_table[hot_page], ccube_hot,
+                occ_norm=float(cfg.nmp_table_size),
+            )
     else:
         cache, ent = env.cache, jnp.zeros((), jnp.int32)
         hot_page = jnp.zeros((), jnp.int32)
@@ -731,89 +738,97 @@ def _epoch_apply(env: EnvState, mid: EpochMid, action: jnp.ndarray,
     en = mid.energy
 
     if flags.any_aimm:
-        # --- apply action (no-ops unless an aimm lane at an invocation) ---
-        topo = get_topology(cfg)
-        hot_page = mid.hot_page
-        nbr = act_mod.random_neighbor(mid.k_nbr, mid.ccube_hot,
-                                      jnp.asarray(topo.nbr),
-                                      jnp.asarray(topo.nbr_valid))
-        diag = act_mod.far_target(mid.ccube_hot, jnp.asarray(topo.far))
-        is_data = (action == NEAR_DATA) | (action == FAR_DATA)
-        is_comp = ((action == NEAR_COMPUTE) | (action == FAR_COMPUTE)
-                   | (action == SOURCE_COMPUTE))
-        data_tgt = jnp.where(action == NEAR_DATA, nbr, diag)
-        comp_tgt = jnp.where(action == NEAR_COMPUTE, nbr,
-                             jnp.where(action == FAR_COMPUTE, diag,
-                                       jnp.asarray(C, jnp.int32)))
+        with jax.named_scope("aimm.apply"):
+            # --- apply action (no-op unless an aimm lane invokes) ---
+            topo = get_topology(cfg)
+            hot_page = mid.hot_page
+            nbr = act_mod.random_neighbor(mid.k_nbr, mid.ccube_hot,
+                                          jnp.asarray(topo.nbr),
+                                          jnp.asarray(topo.nbr_valid))
+            diag = act_mod.far_target(mid.ccube_hot, jnp.asarray(topo.far))
+            is_data = (action == NEAR_DATA) | (action == FAR_DATA)
+            is_comp = ((action == NEAR_COMPUTE) | (action == FAR_COMPUTE)
+                       | (action == SOURCE_COMPUTE))
+            data_tgt = jnp.where(action == NEAR_DATA, nbr, diag)
+            comp_tgt = jnp.where(action == NEAR_COMPUTE, nbr,
+                                 jnp.where(action == FAR_COMPUTE, diag,
+                                           jnp.asarray(C, jnp.int32)))
+            acted = invoke & is_aimm
+            target = jnp.where(acted & is_data, data_tgt,
+                               jnp.where(acted & is_comp, comp_tgt,
+                                         jnp.int32(NO_TARGET)))
 
-        old_cube = env.page_to_cube[hot_page]
-        mig_latency, mig_stall_aimm, mig_loads_aimm = migration_cost(
-            old_cube, data_tgt, rw_pages[hot_page], mid.touches_hot, cfg)
-        moved = is_data & (data_tgt != old_cube) & invoke & is_aimm
-        migrated_aimm = moved.astype(jnp.float32)
-        page_to_cube = env.page_to_cube.at[hot_page].set(
-            jnp.where(moved, data_tgt, old_cube).astype(jnp.int32))
-        mig_latency = jnp.where(moved, mig_latency, 0.0)
-        mig_stall_aimm = jnp.where(moved, mig_stall_aimm, 0.0)
-        mig_loads_aimm = jnp.where(moved, mig_loads_aimm, 0.0)
+            old_cube = env.page_to_cube[hot_page]
+            mig_latency, mig_stall_aimm, mig_loads_aimm = migration_cost(
+                old_cube, data_tgt, rw_pages[hot_page], mid.touches_hot, cfg)
+            moved = is_data & (data_tgt != old_cube) & invoke & is_aimm
+            migrated_aimm = moved.astype(jnp.float32)
+            page_to_cube = env.page_to_cube.at[hot_page].set(
+                jnp.where(moved, data_tgt, old_cube).astype(jnp.int32))
+            mig_latency = jnp.where(moved, mig_latency, 0.0)
+            mig_stall_aimm = jnp.where(moved, mig_stall_aimm, 0.0)
+            mig_loads_aimm = jnp.where(moved, mig_loads_aimm, 0.0)
 
-        # DEFAULT on the selected page restores its default mapping (clears the
-        # compute-remap entry) — gives the agent an undo for stale remaps.
-        entry = jnp.where(is_comp, comp_tgt,
-                          jnp.where(action == DEFAULT,
-                                    jnp.asarray(-1, jnp.int32),
-                                    env.compute_remap[hot_page]))
-        compute_remap = env.compute_remap.at[hot_page].set(
-            jnp.where(invoke & is_aimm, entry,
-                      env.compute_remap[hot_page]).astype(jnp.int32))
-        # Finite compute-remap table: entries expire after remap_ttl epochs
-        # (LRU-style eviction under table pressure) — bounds stale-remap damage.
-        remap_age = jnp.where(compute_remap >= 0, env.remap_age + 1, 0)
-        expired = remap_age > cfg.remap_ttl
-        compute_remap = jnp.where(expired, -1, compute_remap)
-        remap_age = jnp.where(expired, 0, remap_age)
-        remap_age = jnp.where(is_aimm, remap_age, env.remap_age)
-        interval_level = jnp.where(invoke & is_aimm,
-                                   act_mod.adjust_interval(env.interval_level,
-                                                           action),
-                                   env.interval_level)
+            # DEFAULT on the selected page restores its default mapping
+            # (clears the compute-remap entry): the agent's undo for stale
+            # remaps.
+            entry = jnp.where(is_comp, comp_tgt,
+                              jnp.where(action == DEFAULT,
+                                        jnp.asarray(-1, jnp.int32),
+                                        env.compute_remap[hot_page]))
+            compute_remap = env.compute_remap.at[hot_page].set(
+                jnp.where(invoke & is_aimm, entry,
+                          env.compute_remap[hot_page]).astype(jnp.int32))
+            # Finite compute-remap table: entries expire after remap_ttl epochs
+            # (LRU-style eviction under table pressure), which bounds the
+            # damage of a stale remap.
+            remap_age = jnp.where(compute_remap >= 0, env.remap_age + 1, 0)
+            expired = remap_age > cfg.remap_ttl
+            compute_remap = jnp.where(expired, -1, compute_remap)
+            remap_age = jnp.where(expired, 0, remap_age)
+            remap_age = jnp.where(is_aimm, remap_age, env.remap_age)
+            interval_level = jnp.where(invoke & is_aimm,
+                                       act_mod.adjust_interval(
+                                           env.interval_level, action),
+                                       env.interval_level)
 
-        cache = cache._replace(
-            migrations=cache.migrations.at[mid.ent].add(migrated_aimm),
-            mig_hist=jnp.where(moved,
-                               push_hist(cache.mig_hist, mid.ent, mig_latency),
-                               cache.mig_hist),
-            act_hist=jnp.where(invoke & is_aimm,
-                               push_hist(cache.act_hist, mid.ent,
-                                         action.astype(jnp.float32)),
-                               cache.act_hist),
-        )
-        gah = jnp.where(invoke & is_aimm,
-                        jnp.concatenate([env.global_act_hist[1:],
-                                         action[None]]),
-                        env.global_act_hist)
-        recent_pages = jnp.where(invoke & is_aimm,
-                                 jnp.concatenate([env.recent_pages[1:],
-                                                  hot_page[None]]),
-                                 env.recent_pages)
-        prev_state_vec = jnp.where(invoke & is_aimm, mid.svec,
-                                   env.prev_state_vec)
-        prev_action = jnp.where(invoke, action,
-                                env.prev_action).astype(jnp.int32)
+            cache = cache._replace(
+                migrations=cache.migrations.at[mid.ent].add(migrated_aimm),
+                mig_hist=jnp.where(moved,
+                                   push_hist(cache.mig_hist, mid.ent,
+                                             mig_latency),
+                                   cache.mig_hist),
+                act_hist=jnp.where(invoke & is_aimm,
+                                   push_hist(cache.act_hist, mid.ent,
+                                             action.astype(jnp.float32)),
+                                   cache.act_hist),
+            )
+            gah = jnp.where(invoke & is_aimm,
+                            jnp.concatenate([env.global_act_hist[1:],
+                                             action[None]]),
+                            env.global_act_hist)
+            recent_pages = jnp.where(invoke & is_aimm,
+                                     jnp.concatenate([env.recent_pages[1:],
+                                                      hot_page[None]]),
+                                     env.recent_pages)
+            prev_state_vec = jnp.where(invoke & is_aimm, mid.svec,
+                                       env.prev_state_vec)
+            prev_action = jnp.where(invoke, action,
+                                    env.prev_action).astype(jnp.int32)
 
-        # ---- accesses on migrated pages (Fig. 10 stat) ----
-        mig_mask = jnp.where(is_aimm,
-                             env.mig_page_mask.at[hot_page].set(
-                                 jnp.maximum(env.mig_page_mask[hot_page],
-                                             migrated_aimm)),
-                             env.mig_page_mask)
-        acc_mig = (jnp.sum(mig_mask[mid.dest] * mid.valid)
-                   + jnp.sum(mig_mask[mid.src1] * mid.valid)
-                   + jnp.sum(mig_mask[mid.src2] * mid.valid))
+            # ---- accesses on migrated pages (Fig. 10 stat) ----
+            mig_mask = jnp.where(is_aimm,
+                                 env.mig_page_mask.at[hot_page].set(
+                                     jnp.maximum(env.mig_page_mask[hot_page],
+                                                 migrated_aimm)),
+                                 env.mig_page_mask)
+            acc_mig = (jnp.sum(mig_mask[mid.dest] * mid.valid)
+                       + jnp.sum(mig_mask[mid.src1] * mid.valid)
+                       + jnp.sum(mig_mask[mid.src2] * mid.valid))
 
-        aimm_f = is_aimm.astype(jnp.float32)
-        en = en.at[EN_MIG_Q].add(2 * migrated_aimm * aimm_f)
-        en = en.at[EN_MDMA].add(migrated_aimm * cfg.page_flits * aimm_f)
+            aimm_f = is_aimm.astype(jnp.float32)
+            en = en.at[EN_MIG_Q].add(2 * migrated_aimm * aimm_f)
+            en = en.at[EN_MDMA].add(migrated_aimm * cfg.page_flits * aimm_f)
     else:
         page_to_cube = env.page_to_cube
         compute_remap = env.compute_remap
@@ -828,6 +843,7 @@ def _epoch_apply(env: EnvState, mid: EpochMid, action: jnp.ndarray,
         migrated_aimm = jnp.zeros(())
         mig_stall_aimm = jnp.zeros(())
         mig_loads_aimm = jnp.zeros_like(env.pending_mig_loads)
+        target = None
 
     # ---- combine mapper outputs ----
     mig_stall = jnp.where(is_aimm, mig_stall_aimm,
@@ -891,6 +907,8 @@ def _epoch_apply(env: EnvState, mid: EpochMid, action: jnp.ndarray,
         "util": jnp.where(has_ops, mid.util, 0.0),
         "invoke": invoke.astype(jnp.float32), "valid": mid.w_valid,
     }
+    if target is not None:
+        metrics["target"] = target       # NO_TARGET where no remap applied
     return new_env, metrics
 
 
@@ -928,9 +946,10 @@ def _invoke_agent(agent: AgentState, svec: jnp.ndarray, reward: jnp.ndarray,
     bit-for-bit, so running this under the driver's any-lane-invokes cond
     equals the compute-then-mask reference path (tests/test_engine_golden.py).
     """
-    pushed = jax.vmap(agent_mod.observe)(agent, prev_svec, prev_action,
-                                         reward, svec)
-    ag = _sel(commit & prev_ok, pushed, agent)
+    with jax.named_scope("agent.observe"):
+        pushed = jax.vmap(agent_mod.observe)(agent, prev_svec, prev_action,
+                                             reward, svec)
+        ag = _sel(commit & prev_ok, pushed, agent)
     keys = jax.vmap(jax.random.split)(ag.rng)          # (B, 2, key)
     ag = ag._replace(rng=jnp.where(commit[:, None], keys[:, 0], ag.rng))
     k_train = keys[:, 1]
@@ -940,15 +959,18 @@ def _invoke_agent(agent: AgentState, svec: jnp.ndarray, reward: jnp.ndarray,
                                                               k))(a, k_train)
         return _sel(commit, trained, a)
 
-    ready = agent_mod.replay_ready(ag, agent_cfg)
-    if agent_gate == "cond":
-        ag = jax.lax.cond(jnp.any(commit & ready), do_train, lambda a: a, ag)
-    else:
-        ag = do_train(ag)
-    action_g, acted = jax.vmap(
-        lambda al, s, e: agent_mod.act(al, agent_cfg, s, e))(ag, svec,
-                                                             explore)
-    ag = _sel(commit, acted, ag)
+    with jax.named_scope("agent.train"):
+        ready = agent_mod.replay_ready(ag, agent_cfg)
+        if agent_gate == "cond":
+            ag = jax.lax.cond(jnp.any(commit & ready), do_train,
+                              lambda a: a, ag)
+        else:
+            ag = do_train(ag)
+    with jax.named_scope("agent.act"):
+        action_g, acted = jax.vmap(
+            lambda al, s, e: agent_mod.act(al, agent_cfg, s, e))(ag, svec,
+                                                                 explore)
+        ag = _sel(commit, acted, ag)
     action = jnp.where(invoke, action_g,
                        jnp.int32(DEFAULT)).astype(jnp.int32)
     return ag, action
@@ -1117,8 +1139,8 @@ def default_agent_cfg(cfg: NMPConfig) -> AgentConfig:
 
     gamma=0: the tenure reward already integrates the action's effect over its
     own horizon (like-for-like vs the previous kernel iteration), so mapping
-    control is contextual-bandit-shaped; bootstrapping with large gamma only
-    amplified TD noise at these sample counts (see EXPERIMENTS.md §Paper).
+    control is contextual-bandit-shaped, and bootstrapping with a large gamma
+    only adds TD noise at the few hundred invocations an episode makes.
     """
     spec = state_spec_for(cfg)
     return AgentConfig(dqn=DQNConfig(state_dim=spec.dim, n_actions=N_ACTIONS,

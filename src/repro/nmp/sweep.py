@@ -92,8 +92,8 @@ from repro.nmp import partition
 from repro.nmp import plan as plan_mod
 from repro.nmp import spans
 from repro.nmp.config import NMPConfig
-from repro.nmp.engine import (TraceCtx, _init_env, default_agent_cfg,
-                              scan_epochs, state_spec_for)
+from repro.nmp.engine import (NO_TARGET, TraceCtx, _init_env,
+                              default_agent_cfg, scan_epochs, state_spec_for)
 from repro.nmp.plan import GridPlan, group_flags, needs_agent, plan_grid
 from repro.nmp.scenarios import Scenario
 from repro.nmp.stats import energy_breakdown, energy_nj, resample_opc
@@ -189,6 +189,18 @@ def _run_sweep(batch, tom_cands, cfg, spec, agent_cfg, n_epochs, n_episodes,
             "valid_t": jnp.moveaxis(ms["valid"].astype(jnp.uint16), 0, -1),
             "invoke_t": jnp.moveaxis(ms["invoke"].astype(jnp.uint16), 0, -1),
         }
+        if flags.any_aimm:
+            # what an AIMM lane did at each epoch, for a reference that
+            # replays it (0 / NO_TARGET wherever no action applied)
+            out["action_t"] = jnp.moveaxis(ms["action"].astype(jnp.uint8),
+                                           0, -1)
+            out["target_t"] = jnp.moveaxis(ms["target"].astype(jnp.uint8),
+                                           0, -1)
+        if flags.has_agent:
+            # scanned epochs in which the any-lane-invokes agent cond fired
+            fires = jnp.sum(jnp.any(ms["invoke"] > 0, axis=(1, 2)),
+                            dtype=jnp.int32)
+            out["agent_fires"] = jnp.broadcast_to(fires, (L, S))
         return ((agent2 if flags.has_agent else agent), env), out
 
     xs = (jnp.moveaxis(batch["ep_seed"], -1, 0),          # (E, L, S)
@@ -205,7 +217,9 @@ class SweepResult:
     scenarios: list[Scenario]
     cfg: NMPConfig
     metrics: dict[str, np.ndarray]   # (B, E) scalars; energy (B, E, EN_N);
-                                     # opc_t/valid_t/invoke_t (B, E, n_epochs)
+                                     # opc_t/valid_t/invoke_t (B, E, n_epochs),
+                                     # and action_t/target_t (uint8) when a
+                                     # lane is AIMM
     final_env: Any                   # EnvState stacked over the lane axis
     n_episodes: int                  # common (padded) episode count E
     wall_s: float                    # build + compile + run wall time
@@ -215,6 +229,10 @@ class SweepResult:
     store: Any = None                # the PolicyStore holding the grid's
                                      # final agent lineages (None when no
                                      # lane declared a lineage)
+    counters: dict = dataclasses.field(default_factory=dict)
+    # over the learned (live-DQN) groups: `agent_epochs` scanned epochs,
+    # `agent_fires` of them in which the batch's DQN step ran, and
+    # `agent_invocations`, the sum of invoke_t over learned cells
 
     def episode_summary(self, lane: int, episode: int | None = None) -> dict:
         """Per-(lane, episode) summary with the same keys as stats.summarize.
@@ -578,6 +596,7 @@ def _run_grid(scenarios, cfg, agent_cfg, store, call, root):
 
     outs: list = [None] * len(scenarios)
     envs: list = [None] * len(scenarios)
+    learned: list = []               # per learned group: its counters
     staging = AgentStaging() if staging_enabled() else None
     # The store is touched from two threads under async landing: warm
     # checkouts in launch() (main thread) vs lineage write-backs in land()
@@ -616,7 +635,9 @@ def _run_grid(scenarios, cfg, agent_cfg, store, call, root):
                         d2h_bytes=spans.nbytes((out, env_fin))):
             out = partition.host_fetch(out)
             env_fin = partition.host_fetch(env_fin)
-        with spans.span("unfold", **ids, lanes=group.n_lanes):
+        out = dict(out)
+        fires = out.pop("agent_fires", None)
+        with spans.span("unfold", **ids, lanes=group.n_lanes) as unfold:
             pad_l = n_links_max - get_topology(group_cfg).n_links
             if pad_l:
                 env_fin = env_fin._replace(pending_mig_loads=np.pad(
@@ -635,6 +656,16 @@ def _run_grid(scenarios, cfg, agent_cfg, store, call, root):
                                 lambda a, li=li, si=si: np.asarray(a[li, si]),
                                 env_fin))
                     outs[i], envs[i] = cells[si]
+            if fires is not None:
+                counts = {
+                    "agent_epochs": plan.n_epochs * group.n_episodes,
+                    "agent_fires": int(fires[0, 0].sum()),
+                    "agent_invocations": sum(
+                        int(outs[i]["invoke_t"][
+                            :scenarios[i].total_episodes].sum())
+                        for lane in group.lanes for i in lane.indices)}
+                unfold.set_metadata(**counts)
+                learned.append(counts)
             if group.lineage:
                 # Hand every tag's final agent back to the store.  When
                 # several cells share a tag (seed replicas, repeated tags),
@@ -689,7 +720,8 @@ def _run_grid(scenarios, cfg, agent_cfg, store, call, root):
             pool.shutdown(wait=True)
 
     with spans.span("stack", call=call):
-        metrics = {k: np.stack([o[k] for o in outs]) for k in outs[0]}
+        metrics = {k: np.stack([_timeline(o, k) for o in outs])
+                   for k in dict.fromkeys(k for o in outs for k in o)}
         final_env = jax.tree.map(lambda *xs: np.stack(xs), *envs)
         desc = partition.mesh_desc(mesh)
         return SweepResult(scenarios=scenarios, cfg=cfg, metrics=metrics,
@@ -697,13 +729,28 @@ def _run_grid(scenarios, cfg, agent_cfg, store, call, root):
                            wall_s=time.time() - t0, plan=plan,
                            n_devices=desc["n_devices"],
                            mesh_shape=tuple(desc["shape"]),
-                           store=store)
+                           store=store,
+                           counters={k: sum(c[k] for c in learned)
+                                     for k in ("agent_epochs", "agent_fires",
+                                               "agent_invocations")})
+
+
+def _timeline(out: dict, key: str) -> np.ndarray:
+    """A cell's `key` statistic; the AIMM-only action/target timelines of
+    a cell from a group without AIMM lanes are filled in on the host (no
+    action, NO_TARGET), so its program neither computes nor fetches them."""
+    if key in out:
+        return out[key]
+    fill = {"action_t": 0, "target_t": NO_TARGET}[key]
+    return np.full(out["invoke_t"].shape, fill, np.uint8)
 
 
 def run_grid_serial(scenarios: Sequence[Scenario],
                     cfg: NMPConfig = NMPConfig()) -> list[dict]:
     """Reference serial loop over the same grid (one run_episode/run_program
-    per lane). Used by the equivalence tests and the benchmark comparison."""
+    per lane). Used by the equivalence tests and the benchmark comparison.
+    An AIMM lane's summary also holds its last episode's `action_t` and
+    `target_t` timelines (uint8, as `run_grid` lands them)."""
     from repro.nmp.engine import run_episode, run_program
     from repro.nmp.stats import summarize
     out = []
@@ -719,10 +766,14 @@ def run_grid_serial(scenarios: Sequence[Scenario],
                     sc.trace, sc_cfg, sc.technique, "aimm",
                     agent=results[-1].agent, seed=sc.seed, explore=False,
                     page_table=sc.page_table))
-            out.append(summarize(results[-1]))
+            res = results[-1]
         else:
             res = run_episode(sc.trace, sc_cfg, sc.technique, sc.mapper,
                               seed=sc.seed, page_table=sc.page_table,
                               forced_action=sc.forced_action)
-            out.append(summarize(res))
+        summary = summarize(res)
+        if sc.mapper == "aimm":
+            summary["action_t"] = np.asarray(res.metrics["action"], np.uint8)
+            summary["target_t"] = np.asarray(res.metrics["target"], np.uint8)
+        out.append(summary)
     return out

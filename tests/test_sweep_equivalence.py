@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.nmp import NMPConfig, make_trace
-from repro.nmp.engine import run_episode, run_program
+from repro.nmp.engine import NO_TARGET, run_episode, run_program
 from repro.nmp.scenarios import (Scenario, forced_action_grid, seed_variants,
                                  single_program_grid)
 from repro.nmp.stats import summarize
@@ -149,3 +149,71 @@ def test_single_program_grid_builder_covers_cells():
     assert len(grid) == 2 * 2 * 2
     names = {sc.name for sc in grid}
     assert len(names) == len(grid)          # unique lane names
+
+
+def test_learned_timelines_match_serial_with_seed_axis():
+    """`action_t` / `target_t` of learned lanes folded three to a lane equal
+    `run_program`'s per-episode `action` / `target` bit for bit; a lane of
+    a group with no AIMM lane gets its timelines filled in on the host."""
+    tr = make_trace("KM", n_ops=384)
+    grid = seed_variants(Scenario(name="KM/aimm", trace=tr, mapper="aimm",
+                                  episodes=2), seeds=(0, 1, 2))
+    grid.append(Scenario(name="KM/none", trace=tr, episodes=2))
+    res = run_grid(grid, CFG)
+    assert res.plan.groups[0].n_seeds == 3
+    m = res.metrics
+    for i, sc in enumerate(grid[:3]):
+        serial = run_program(sc.trace, CFG, sc.technique, "aimm",
+                             episodes=sc.episodes, seed=sc.seed)
+        for e in range(sc.episodes):
+            for key, name in (("action_t", "action"), ("target_t", "target")):
+                want = np.asarray(serial[e].metrics[name], np.uint8)
+                assert m[key].dtype == np.uint8
+                np.testing.assert_array_equal(m[key][i, e], want,
+                                              err_msg=f"{sc.name}/{key}/{e}")
+    assert (m["action_t"][3] == 0).all()
+    assert (m["target_t"][3] == NO_TARGET).all()
+    inv = m["invoke_t"][:3] > 0
+    assert ((m["target_t"][:3] != NO_TARGET) <= inv).all()
+    assert ((m["action_t"][:3] != 0) <= inv).all()
+
+
+def test_baseline_grid_lands_no_timelines(monkeypatch):
+    """A none/tom grid neither computes nor fetches the AIMM timelines or
+    the agent counters: it fetches the statistics it fetched before."""
+    from repro.nmp import partition, sweep
+    fetched = []
+    host_fetch = partition.host_fetch
+
+    def recording(tree):
+        if isinstance(tree, dict):
+            fetched.append(set(tree))
+        return host_fetch(tree)
+    monkeypatch.setattr(sweep.partition, "host_fetch", recording)
+    tr = make_trace("RBM", n_ops=256)
+    res = run_grid([Scenario(name=f"RBM/{m}", trace=tr, mapper=m)
+                    for m in ("none", "tom")], CFG)
+    stats = {"cycles", "ops", "hops_sum", "util_sum", "epochs", "migrations",
+             "pages_migrated", "access_total", "access_on_migrated",
+             "energy", "opc_t", "valid_t", "invoke_t"}
+    assert fetched == [stats]
+    assert set(res.metrics) == stats
+    assert res.counters == {"agent_epochs": 0, "agent_fires": 0,
+                            "agent_invocations": 0}
+
+
+def test_agent_counters_match_invoke_t():
+    """`agent_fires` counts the scanned epochs in which any learned cell
+    invoked (the predicate of the agent cond), `agent_invocations` the
+    invocations of every learned cell, over lanes of unequal length."""
+    grid = [Scenario(name=f"{app}/aimm", trace=make_trace(app, n_ops=n),
+                     mapper="aimm", episodes=2, seed=s)
+            for app, n in (("KM", 256), ("SPMV", 512)) for s in (0, 1)]
+    grid.append(Scenario(name="KM/forced", trace=grid[0].trace,
+                         mapper="aimm", forced_action=6))
+    res = run_grid(grid, CFG)
+    inv = res.metrics["invoke_t"][:4]                  # learned cells
+    assert res.counters["agent_fires"] == int(np.any(inv > 0, axis=0).sum())
+    assert res.counters["agent_invocations"] == int(inv.sum())
+    assert res.counters["agent_epochs"] == 2 * res.plan.n_epochs
+    assert 0 < res.counters["agent_fires"] <= res.counters["agent_epochs"]
