@@ -73,7 +73,12 @@ def zeros_params(cfg: DQNConfig) -> PyTree:
 # passes `tree_sum` to `adamw`).  Products pass an optimization barrier
 # (`_product`) before they are summed, which asks the compiler not to fuse
 # a multiply into the adds (a fused multiply-add rounds once, not twice).
-# Both are requests to the compiler, not guarantees.  What was checked: on
+# Both are requests to the compiler, not guarantees: the CPU compiler drops
+# the barrier and fuses products into their adds, so a jitted CPU program
+# rounds them once (op-by-op execution rounds them twice, as the chip does).
+# A TPU program on one device runs the three contractions of `dense` in the
+# `order_fixed_dense` Pallas kernel, which keeps the products and the tree
+# in VMEM and gives the jnp path's bits (`_matmul`).  What was checked: on
 # a TPU v5e, act Q and a TD step at vmap widths 1, 2, 7 and 21 equal width
 # 27 bit for bit; on the CPU, tests/test_dqn.py pins the same at widths 1,
 # 2 and 27 with weights entering the program as inputs, as the sweep's scan
@@ -97,12 +102,34 @@ def tree_sum(x: jnp.ndarray, axis: int, keepdims: bool = False
     return jnp.moveaxis(x, 0, axis) if keepdims else x[0]
 
 
+def _sharded() -> bool:
+    """Whether the program being traced is split over several devices (the
+    sweep enters its mesh as the abstract mesh).  GSPMD cannot partition a
+    Pallas kernel, so such a program keeps the jnp path, which it splits
+    cell by cell."""
+    return jax.sharding.get_abstract_mesh().size > 1
+
+
+def _matmul(a: jnp.ndarray, b: jnp.ndarray, jnp_path) -> jnp.ndarray:
+    """a @ b for a (I, R), b (R, J), summed over R by `tree_sum`, as
+    `jnp_path()` computes it.  A TPU program on one device computes the
+    same bits with the `order_fixed_dense` kernel, which keeps the products
+    and the tree in VMEM; the choice is made when the program is lowered
+    for a platform."""
+    if _sharded():
+        return jnp_path()
+    # imported here: the kernel takes `tree_sum` from this module
+    from repro.kernels.order_fixed_dense.kernel import order_fixed_matmul
+    return jax.lax.platform_dependent(a, b, tpu=order_fixed_matmul,
+                                      default=lambda a, b: jnp_path())
+
+
 @jax.custom_vjp
 def dense(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """x @ w + b for x (B, K), w (K, N), b (N,), in a fixed summation order
     (its gradients too: the transpose of a broadcast would be an XLA
     reduction)."""
-    return tree_sum(_product(x[:, :, None], w), 1) + b
+    return _matmul(x, w, lambda: tree_sum(_product(x[:, :, None], w), 1)) + b
 
 
 def _dense_fwd(x, w, b):
@@ -111,8 +138,9 @@ def _dense_fwd(x, w, b):
 
 def _dense_bwd(res, g):
     x, w = res
-    return (tree_sum(_product(g[:, None, :], w), 2),
-            tree_sum(_product(x[:, :, None], g[:, None, :]), 0),
+    return (_matmul(g, w.T, lambda: tree_sum(_product(g[:, None, :], w), 2)),
+            _matmul(x.T, g, lambda: tree_sum(
+                _product(x[:, :, None], g[:, None, :]), 0)),
             tree_sum(g, 0))
 
 

@@ -74,6 +74,7 @@ lane axis is sharded over devices or not, and however seeds are folded
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -473,6 +474,18 @@ def executed_flags(group, n_seeds: int):
     return group.flags._replace(share_seed_inv=share)
 
 
+def mesh_scope(batch):
+    """The context a sweep program is traced in: the abstract mesh of the
+    devices its batch is split over, when there are several.  The program
+    reads it to keep the agent's contractions off the order-fixed kernel,
+    which GSPMD cannot split (`core.dqn`)."""
+    sharding = getattr(batch["ep_seed"], "sharding", None)
+    mesh = getattr(sharding, "mesh", None)
+    if mesh is None or mesh.size <= 1:
+        return contextlib.nullcontext()
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+
+
 def dispatch_sweep(batch, tom_cands, group_cfg: NMPConfig, spec, agent_cfg,
                    n_epochs: int, n_episodes: int, ring_len: int, flags,
                    warm_agent=None, want_agent: bool = False,
@@ -484,7 +497,8 @@ def dispatch_sweep(batch, tom_cands, group_cfg: NMPConfig, spec, agent_cfg,
     when the values are needed, and build the *next* batch in between to
     hide its host->device transfer behind the running program.  `ids`
     (`call`, `group`) tag the `dispatch` host span (`nmp.spans`)."""
-    with warnings.catch_warnings(), spans.span("dispatch", **(ids or {})):
+    with (warnings.catch_warnings(), spans.span("dispatch", **(ids or {})),
+          mesh_scope(batch)):
         # int trace/ctx buffers have no same-shaped outputs to reuse;
         # their donation being unusable is expected, not a leak.
         warnings.filterwarnings(

@@ -196,3 +196,64 @@ def test_adamw_clip_bounds_global_norm(leaf_sum):
                                   jnp.int32(0))[1]
     np.testing.assert_allclose(global_norm(unclipped["m"]),
                                0.1 * global_norm(grads), rtol=1e-5)
+
+
+def _kernel_matmul(a, b, jnp_path):
+    """`dqn._matmul` sent through the order-fixed kernel in interpret mode,
+    as a TPU program on one device runs it."""
+    from repro.kernels.order_fixed_dense.kernel import order_fixed_matmul
+    return order_fixed_matmul(a, b, interpret=True)
+
+
+@pytest.mark.parametrize("rows", [1, 64])
+@pytest.mark.parametrize("k,n", [(106, 128), (128, 128), (128, 1), (128, 8)])
+def test_order_fixed_kernel_equals_dense(monkeypatch, rows, k, n):
+    """The kernel gives `dense`'s bits in the forward pass, `dx` and `dW` at
+    the agent's widths (the paper's 106-wide state exercises every padded
+    level of the tree: 106 -> 53 -> 54 -> 27 -> 28 -> 14 -> 7 -> 8), signed
+    zeros included.  `dense` runs op by op: a jitted CPU program fuses each
+    product into the add that takes it, which rounds once, not twice."""
+    ks = jax.random.split(jax.random.PRNGKey(rows * 1000 + k + n), 4)
+    x = np.array(jax.random.normal(ks[0], (rows, k)))
+    w = np.array(jax.random.normal(ks[1], (k, n)))
+    b = np.asarray(jax.random.normal(ks[2], (n,)))
+    g = np.array(jax.random.normal(ks[3], (rows, n)))
+    x[0] = -0.0                 # a row of -0: outputs that sum zeros alone
+    x[:, ::5] = -0.0
+    w[::7] = -0.0
+    g[:, ::3] = -0.0
+
+    def forward_and_vjp(x, w, b, g):
+        y, vjp = jax.vjp(dqn.dense, x, w, b)
+        return (y,) + vjp(g)
+
+    want = forward_and_vjp(x, w, b, g)
+    monkeypatch.setattr(dqn, "_matmul", _kernel_matmul)
+    got = jax.jit(forward_and_vjp)(x, w, b, g)
+    for name, a, e in zip(("y", "dx", "dw", "db"), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(e), name)
+        np.testing.assert_array_equal(np.signbit(a), np.signbit(e), name)
+
+
+def test_kernel_td_step_bits_do_not_depend_on_batch_width(monkeypatch):
+    """Through the order-fixed kernel, a cell's TD step gives the same bits
+    vmapped alone and among 27 cells."""
+    monkeypatch.setattr(dqn, "_matmul", _kernel_matmul)
+    cfg = AgentConfig(dqn=DQNConfig(state_dim=10, n_actions=4), min_replay=4)
+
+    def trained(seed):
+        ag = A.cold_start(seed, cfg)
+        for i in range(12):
+            k = jax.random.fold_in(jax.random.PRNGKey(seed + 100), i)
+            ks = jax.random.split(k, 3)
+            ag = A.observe(ag, jax.random.normal(ks[0], (10,)), i % 4,
+                           jax.random.normal(ks[1], ()),
+                           jax.random.normal(ks[2], (10,)))
+        return A.train_step(ag, cfg, jax.random.PRNGKey(seed + 7)).params
+
+    f = jax.jit(jax.vmap(trained))
+    wide = f(jnp.arange(27))
+    for j in (0, 26):
+        alone = f(jnp.arange(j, j + 1))
+        for a, b in zip(jax.tree.leaves(wide), jax.tree.leaves(alone)):
+            np.testing.assert_array_equal(np.asarray(a)[j], np.asarray(b)[0])

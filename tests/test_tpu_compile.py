@@ -65,6 +65,35 @@ def test_qnet_kernel_compiles(one_chip, rows):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_names(hlo: str) -> list[str]:
+    """The instruction names of the Pallas kernels in a compiled program."""
+    return [line.split("=", 1)[0].split()[-1].lstrip("%")
+            for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("rows,k,n", [(64, STATE_DIM, 128), (64, 128, 128),
+                                      (64, 128, 1), (64, 128, N_ACTIONS),
+                                      (1, STATE_DIM, 128)],
+                         ids=["layer0", "layer1", "value", "advantage",
+                              "act"])
+def test_order_fixed_dense_compiles(one_chip, rows, k, n):
+    """`dqn.dense` and its VJP at the agent's widths, vmapped over the 81
+    cells of the learned benchmark grid, lowered for the chip: the forward
+    pass and both contraction cotangents are the order-fixed kernel."""
+    cells = 81
+
+    def step(x, w, b, g):
+        y, vjp = jax.vjp(dqn.dense, x, w, b)
+        return (y,) + vjp(g)
+
+    args = [_sds((cells,) + s, jnp.float32, one_chip)
+            for s in ((rows, k), (k, n), (n,), (rows, n))]
+    hlo = jax.jit(jax.vmap(step)).lower(*args).compile().as_text()
+    names = _kernel_names(hlo)
+    assert len(names) == 3 and all("order_fixed_dense" in n for n in names)
+
+
 def test_backends_auto_resolve_to_jnp(monkeypatch):
     monkeypatch.delenv(epoch_ops.ENV_KNOB, raising=False)
     assert epoch_ops.resolve_backend() == "jnp"
@@ -75,8 +104,11 @@ def test_backends_auto_resolve_to_jnp(monkeypatch):
 def test_sweep_program_compiles(topo, monkeypatch, n_dev):
     """The learned-agent group of a paper-shaped grid (AIMM lanes of every
     technique, PEI's top-k live) through plan -> build batch -> `_run_sweep`
-    with the defaults (jnp epoch core, order-fixed Q-network), on one chip
-    and sharded over four."""
+    with the defaults, on one chip and sharded over four.  The epoch core is
+    jnp on both.  On one chip the Q-network's contractions are the
+    order-fixed kernel, the program's only Pallas kernel; sharded, GSPMD
+    cannot split that kernel, so the program keeps the jnp contractions and
+    each chip holds only its own cells' agents."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.nmp import partition
     from repro.nmp import plan as plan_mod
@@ -104,13 +136,21 @@ def test_sweep_program_compiles(topo, monkeypatch, n_dev):
     lane_sh = NamedSharding(mesh, P(partition.LANE_AXIS))
     cell_sh = NamedSharding(mesh, P(partition.LANE_AXIS,
                                     partition.SEED_AXIS))
-    lowered = sweep._run_sweep.lower(
-        {k: _sds(np.shape(v), np.asarray(v).dtype,
-                 cell_sh if k == "ep_seed" else lane_sh)
-         for k, v in batch.items()},
-        _sds(tom.shape, tom.dtype, NamedSharding(mesh, P())), cfg,
-        state_spec_for(cfg), default_agent_cfg(cfg), plan.n_epochs,
-        group.n_episodes, plan.ring_len, flags)
+    shapes = {k: _sds(np.shape(v), np.asarray(v).dtype,
+                      cell_sh if k == "ep_seed" else lane_sh)
+              for k, v in batch.items()}
+    with sweep.mesh_scope(shapes):
+        lowered = sweep._run_sweep.lower(
+            shapes, _sds(tom.shape, tom.dtype, NamedSharding(mesh, P())),
+            cfg, state_spec_for(cfg), default_agent_cfg(cfg), plan.n_epochs,
+            group.n_episodes, plan.ring_len, flags)
     compiled = lowered.compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    hlo = compiled.as_text()
+    names = _kernel_names(hlo)
+    if n_dev == 1:
+        assert names and all(n.startswith("order_fixed_dense")
+                             for n in names)
+    else:
+        assert not names
+        assert "all-gather" not in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
