@@ -14,19 +14,16 @@
 #                     bit-identity across shapes (forced 4-device subprocess),
 #                     seed-share on/off equivalence, shard packing, and the
 #                     2-local-process jax.distributed scaffolding
-#   make test-pallas  the Pallas parity suite (tests/test_pallas_parity.py):
-#                     fused epoch kernel + dueling-qnet kernel in interpret
-#                     mode on CPU, pinned bit-identical against the jnp path
-#                     and the engine goldens, plus the async-landing /
-#                     agent-staging equivalence checks
+#   make test-pallas  the Pallas kernel on the chip path (order_fixed_dense,
+#                     the agent's Q-network contractions) in interpret mode
+#                     on CPU, pinned bit-identical against `dqn.dense`
 #   make bench-smoke  smallest benchmark slice (fig5 + the engine perf record
 #                     + the continual warm-vs-cold record + the multi-tenant
 #                     serving record + the fault-tolerance record + the
-#                     topology-axis record + the fleet-scale record + the
-#                     epoch-kernel record: writes bench_out/BENCH_engine.json,
-#                     BENCH_continual.json, BENCH_serving.json,
-#                     BENCH_faults.json, BENCH_topology.json,
-#                     BENCH_fleet.json and BENCH_epoch_kernel.json)
+#                     topology-axis record + the fleet-scale record: writes
+#                     bench_out/BENCH_engine.json, BENCH_continual.json,
+#                     BENCH_serving.json, BENCH_faults.json,
+#                     BENCH_topology.json and BENCH_fleet.json)
 #   make bench-continual  just the continual-stream warm-vs-cold benchmark
 #   make bench-serving    just the multi-tenant serving benchmark (64 tenant
 #                         streams through 16 resident slot programs)
@@ -34,8 +31,6 @@
 #                         + the divergence guard's no-fault overhead)
 #   make bench-topology   just the topology-axis benchmark (per-interconnect
 #                         learned-AIMM vs baseline + mesh warm-grid guard)
-#   make bench-epoch      just the epoch-kernel benchmark (fused backend +
-#                         async landing + agent staging vs PR 8 emulation)
 #   make bench        every benchmark figure (BENCH_FULL=1 for paper scale)
 #   make profile      JAX profiler trace of one batched grid -> bench_out/profile
 
@@ -46,7 +41,7 @@ export PYTHONPATH
 
 .PHONY: test test-fast test-4dev test-faults test-fleet test-pallas \
 	bench-smoke bench-continual bench-serving bench-faults bench-topology \
-	bench-fleet bench-epoch bench profile
+	bench-fleet bench profile
 
 test:
 	$(PY) -m pytest -x -q
@@ -74,15 +69,13 @@ test-faults:
 test-fleet:
 	$(PY) -m pytest -x -q tests/test_fleet.py
 
-# Pallas parity suite: the fused epoch kernel and the dueling-qnet kernel in
-# interpret mode on CPU, pinned against the jnp reference path and the
-# engine goldens (BodyFlags on/off, S==1 vs S>1, knob validation, and the
-# async-landing / agent-staging bit-identity checks ride along).
+# Pallas lane: the order-fixed dense kernel (the one Pallas kernel a chip
+# program runs) in interpret mode on CPU, pinned against `dqn.dense`.
 test-pallas:
-	$(PY) -m pytest -x -q tests/test_pallas_parity.py
+	$(PY) -m pytest -x -q tests/test_dqn.py -k order_fixed
 
 bench-smoke:
-	BENCH_ONLY=fig5,engine,continual,serving,faults,topology,fleet,epoch_kernel $(PY) benchmarks/run.py
+	BENCH_ONLY=fig5,engine,continual,serving,faults,topology,fleet $(PY) benchmarks/run.py
 
 bench-continual:
 	BENCH_ONLY=continual $(PY) benchmarks/run.py
@@ -98,9 +91,6 @@ bench-topology:
 
 bench-fleet:
 	BENCH_ONLY=fleet $(PY) benchmarks/run.py
-
-bench-epoch:
-	BENCH_ONLY=epoch_kernel $(PY) benchmarks/run.py
 
 bench:
 	$(PY) benchmarks/run.py

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import Timer, emit
-from repro.kernels.dueling_qnet.ref import dueling_qnet_ref
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssd_scan.ref import ssd_ref
 
@@ -27,14 +26,6 @@ def _time(f, *args, iters=5):
 
 def run():
     r = np.random.default_rng(0)
-    # qnet: replay-batch inference
-    S, H, A, B = 128, 128, 8, 256
-    params = [jnp.asarray(r.standard_normal(s).astype(np.float32)) * 0.2
-              for s in ((S, H), (H,), (H, H), (H,), (H, 1), (1,), (H, A), (A,))]
-    x = jnp.asarray(r.standard_normal((B, S)).astype(np.float32))
-    f = jax.jit(lambda x: dueling_qnet_ref(x, *params))
-    emit("kernel/dueling_qnet_ref_b256", _time(f, x), "q_inference")
-
     # flash attention ref at 2k
     q = jnp.asarray(r.standard_normal((1, 8, 2048, 64)).astype(np.float32))
     f = jax.jit(lambda q: attention_ref(q, q, q))

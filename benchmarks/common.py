@@ -67,7 +67,7 @@ class Timer:
 @contextlib.contextmanager
 def env_overrides(**kv):
     """Temporarily set/clear env knobs (None clears).  Knobs like
-    REPRO_SWEEP_MESH / REPRO_EPOCH_BACKEND are read per run_grid call (the
+    REPRO_SWEEP_MESH are read per run_grid call (the
     resolved value is a static jit argument), so flipping them between calls
     selects distinct resident programs without recompiling."""
     old = {k: os.environ.get(k) for k in kv}

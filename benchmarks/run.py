@@ -28,7 +28,6 @@ MODULES = [
     ("serving", "benchmarks.bench_serving"),
     ("faults", "benchmarks.bench_faults"),
     ("topology_axis", "benchmarks.bench_topology"),
-    ("epoch_kernel", "benchmarks.bench_epoch_kernel"),
     ("fig13_sensitivity", "benchmarks.bench_sensitivity"),
     ("fig14_energy", "benchmarks.bench_energy"),
     ("kernels", "benchmarks.bench_kernels"),

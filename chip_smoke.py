@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 import traceback
@@ -252,14 +251,11 @@ def main() -> int:
     entries = (len(list(Path(cache_dir).iterdir()))
                if Path(cache_dir).is_dir() else 0)
     emit("setup", device_kind=repr(dev.device_kind), compile_cache=cache_dir,
-         cache_entries_at_start=entries,
-         epoch_backend=os.environ.get("REPRO_EPOCH_BACKEND", "auto"))
+         cache_entries_at_start=entries)
 
     import jax
     from repro.configs.aimm_nmp import PAPER_4X4
-    from repro.kernels.epoch_fused import resolve_backend
     from repro.nmp.engine import default_agent_cfg, state_spec_for
-    assert resolve_backend() == "jnp", "the chip default epoch core is jnp"
     ctx = {"cfg": PAPER_4X4, "spec": state_spec_for(PAPER_4X4),
            "agent_cfg": default_agent_cfg(PAPER_4X4)}
 

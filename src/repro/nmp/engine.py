@@ -81,8 +81,8 @@ from repro.core.agent import AgentConfig, AgentState
 from repro.core.dqn import DQNConfig
 from repro.core.reward import compute_reward
 from repro.core.state import StateSpec, build_state
-from repro.kernels.epoch_fused import ops as epoch_ops
 from repro.nmp import baselines
+from repro.nmp import epoch_stages
 from repro.nmp.config import NMPConfig
 from repro.nmp.migration import migration_cost
 from repro.nmp.paging import (PageInfoCache, default_alloc, init_page_cache,
@@ -138,22 +138,12 @@ class BodyFlags(NamedTuple):
     valid masks, row-buffer stamps, PEI thresholds, page-touch counts) once
     per lane and broadcast it across the S seed replicas instead of
     recomputing it S times.  Bit-identical either way; compiled out (flag
-    False) when the executed seed axis is width 1.
-
-    `epoch_backend` is the resolved REPRO_EPOCH_BACKEND (one of jnp /
-    pallas / pallas_interpret — see repro.kernels.epoch_fused.ops): the
-    epoch simulation core runs either as the gather/einsum jnp path (what
-    `auto` resolves to on every platform, TPU included) or as the fused
-    Pallas kernel, an explicit opt-in that the TPU compiler refuses today.
-    Carrying it here (a static jit argument everywhere flags flow) means
-    flipping the knob selects a distinct compiled program instead of being
-    frozen into a resident one."""
+    False) when the executed seed axis is width 1."""
     has_agent: bool = False     # a live DQN (aimm lanes with a learned policy)
     any_aimm: bool = False      # hot-page selection / action application
     any_tom: bool = False       # TOM candidate scoring + commit
     pei_k: int = 0              # static top_k width for the PEI threshold
     share_seed_inv: bool = False  # hoist seed-invariant work out of seed vmap
-    epoch_backend: str = "jnp"  # resolved epoch-core backend (see above)
 
 
 def pei_hot_index(n_pages: int, cfg: NMPConfig) -> int:
@@ -178,7 +168,6 @@ def episode_flags(trace: Trace, cfg: NMPConfig, technique: str, mapper: str,
         any_aimm=mapper == "aimm",
         any_tom=mapper == "tom",
         pei_k=pei_top_k(trace.n_pages, cfg) if technique == "pei" else 0,
-        epoch_backend=epoch_ops.resolve_backend(),
     )
 
 
@@ -414,18 +403,15 @@ def _shared_epoch(env: EnvState, trace: dict, ctx: TraceCtx, cfg: NMPConfig,
                   tom_scores_all: jnp.ndarray | None = None) -> SharedEpoch:
     """Compute the seed-invariant epoch quantities from one lane's env (any
     seed replica — seed slot 0 by convention).  The stage math lives in
-    repro.kernels.epoch_fused.ref (one source for the jnp path and the
-    Pallas kernel body); bit-identical to the inline computations these
-    replaced in `_epoch_sim`, on any backend."""
+    `epoch_stages.shared_stage`."""
     dest, src1, src2, valid = _fetch_window(env, trace, ctx, cfg)
     w_valid = valid.sum()
     has_ops = w_valid > 0
 
-    parts = epoch_ops.shared_parts(
+    parts = epoch_stages.shared_stage(
         dest, src1, src2, valid, env.epochs, env.rb_stamp,
         env.page_access_ema, ctx.n_pages, ctx.pei_idx,
-        pei_k=flags.pei_k, aimm=flags.any_aimm,
-        backend=flags.epoch_backend)
+        pei_k=flags.pei_k, aimm=flags.any_aimm)
     # Only the PEI threshold reads the access EMA; without PEI lanes the
     # decay + triple scatter is compiled out and the EMA rides unchanged.
     page_ema = (parts.page_ema if parts.page_ema is not None
@@ -462,21 +448,12 @@ def _epoch_sim(env: EnvState, trace: dict, tom_cands: jnp.ndarray,
     is_aimm = ctx.mapper == MAPPER_ID["aimm"]
 
     # ---- seed-invariant half: window fetch, stamps, thresholds, counts ----
-    # On a non-jnp backend with no precomputed SharedEpoch (serial runs,
-    # S==1 programs), the shared half fuses into the same kernel launch as
-    # the route half below instead of running as a separate stage.
-    fused = shared is None and flags.epoch_backend != "jnp"
-    if shared is None and not fused:
+    if shared is None:
         shared = _shared_epoch(env, trace, ctx, cfg, flags, tom_scores_all)
-    if fused:
-        dest, src1, src2, valid = _fetch_window(env, trace, ctx, cfg)
-        w_valid = valid.sum()
-        has_ops = w_valid > 0
-    else:
-        dest, src1, src2, valid = (shared.dest, shared.src1, shared.src2,
-                                   shared.valid)
-        w_valid = shared.w_valid
-        has_ops = shared.has_ops
+    dest, src1, src2, valid = (shared.dest, shared.src1, shared.src2,
+                               shared.valid)
+    w_valid = shared.w_valid
+    has_ops = shared.has_ops
 
     # ---- data mapping (TOM may override the page table) ----
     if flags.any_tom:
@@ -485,37 +462,19 @@ def _epoch_sim(env: EnvState, trace: dict, tom_cands: jnp.ndarray,
                               env.page_to_cube)
     else:
         eff_table = env.page_to_cube
-    # ---- schedule + route + per-cube counts: the fused epoch core ----
-    # Stage math lives in repro.kernels.epoch_fused (ref.py is the single
-    # source for the jnp path and the Pallas kernel body): effective-table
-    # gathers, technique scheduling (PEI hot-source placement, AIMM
-    # compute-remap override), per-link flit loads, hop counts, and the
-    # per-cube compute/access/row-buffer-distinct/MC-queue counts.  Counts
-    # and route weights are exact small integers in f32, so every reduction
-    # is bit-exact regardless of accumulation order or backend.
-    if fused:
-        sparts, rparts = epoch_ops.fused_parts(
-            dest, src1, src2, valid, env.epochs, env.rb_stamp,
-            env.page_access_ema, ctx.n_pages, ctx.pei_idx, eff_table,
-            env.compute_remap, ctx.technique, is_aimm,
-            env.pending_mig_loads, topo, pei_k=flags.pei_k,
-            aimm=flags.any_aimm, n_mcs=cfg.n_mcs,
-            packet_flits=cfg.packet_flits, backend=flags.epoch_backend)
-        shared = SharedEpoch(
-            dest=dest, src1=src1, src2=src2, valid=valid, w_valid=w_valid,
-            has_ops=has_ops, rb_stamp=sparts.rb_stamp,
-            rb_winner=sparts.rb_winner,
-            page_ema=(sparts.page_ema if sparts.page_ema is not None
-                      else env.page_access_ema),
-            pei_hot1=sparts.pei_hot1, pei_hot2=sparts.pei_hot2,
-            touch_cnt=sparts.touch_cnt, tom_scores=tom_scores_all)
-    else:
-        rparts = epoch_ops.route_parts(
-            dest, src1, src2, valid, shared.rb_winner, shared.pei_hot1,
-            shared.pei_hot2, eff_table, env.compute_remap, ctx.technique,
-            is_aimm, env.pending_mig_loads, topo, pei_k=flags.pei_k,
-            aimm=flags.any_aimm, n_mcs=cfg.n_mcs,
-            packet_flits=cfg.packet_flits, backend=flags.epoch_backend)
+    # ---- schedule + route + per-cube counts (`epoch_stages.route_stage`):
+    # effective-table gathers, technique scheduling (PEI hot-source
+    # placement, AIMM compute-remap override), per-link flit loads, hop
+    # counts, and the per-cube compute/access/row-buffer-distinct/MC-queue
+    # counts.  Counts and route weights are exact small integers in f32, so
+    # every reduction is bit-exact regardless of accumulation order.
+    rparts = epoch_stages.route_stage(
+        dest, src1, src2, valid, shared.rb_winner, shared.pei_hot1,
+        shared.pei_hot2, eff_table, env.compute_remap, ctx.technique,
+        is_aimm, env.pending_mig_loads, jnp.asarray(topo.route_links),
+        jnp.asarray(topo.hops), jnp.asarray(topo.nearest_mc),
+        pei=flags.pei_k > 0, aimm=flags.any_aimm, n_mcs=cfg.n_mcs,
+        packet_flits=cfg.packet_flits)
     ccube, loads, hops_op = rparts.ccube, rparts.loads, rparts.hops_op
     ops_c, acc_c, distinct_c, mcq = (rparts.ops_c, rparts.acc_c,
                                      rparts.distinct_c, rparts.mcq)
@@ -705,19 +664,16 @@ def _epoch_sim(env: EnvState, trace: dict, tom_cands: jnp.ndarray,
 
 
 def _tom_window_scores(env: EnvState, trace: dict, tom_cands: jnp.ndarray,
-                       ctx: TraceCtx, cfg: NMPConfig,
-                       backend: str = "jnp") -> jnp.ndarray:
+                       ctx: TraceCtx, cfg: NMPConfig) -> jnp.ndarray:
     """Co-location scores of every TOM candidate mapping on this lane's
     current window: the expensive profiling-phase work, split out of
     `_epoch_sim` so the epoch driver can gate it under `lax.cond` on "any
     lane is in a profiling phase" (the same shape as the DQN invocation
     gate).  Recomputes the window fetch (`_fetch_window`, three slices + the
-    mask) — cheap next to scoring K candidates — and is bit-identical to the
-    historical inline computation on any backend (the scoring math lives in
-    repro.kernels.epoch_fused.ref)."""
+    mask) — cheap next to scoring K candidates (`epoch_stages.tom_stage`)."""
     dest, src1, src2, valid = _fetch_window(env, trace, ctx, cfg)
-    return epoch_ops.tom_scores(dest, src1, src2, valid, tom_cands,
-                                cfg.n_cubes, backend=backend)
+    return epoch_stages.tom_stage(dest, src1, src2, valid, tom_cands,
+                                  cfg.n_cubes)
 
 
 # ---------------------------------------------------------------------------
@@ -1015,8 +971,7 @@ def _epoch_batched(env: EnvState, agent: AgentState | None, trace: dict,
         K = tom_cands.shape[0]
 
         def scores_fn(e, t, c):
-            return _tom_window_scores(e, t, tom_cands, c, cfg,
-                                      flags.epoch_backend)
+            return _tom_window_scores(e, t, tom_cands, c, cfg)
 
         score_env = env0 if share else env
         vscores = (jax.vmap(scores_fn) if share else

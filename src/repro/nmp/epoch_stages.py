@@ -1,33 +1,20 @@
-"""Single-source stage math for the fused epoch core.
-
-The epoch hot path (engine `_shared_epoch` + the schedule/route/count block
-of `_epoch_sim`) is split here into three pure stage functions so the jnp
-dispatch path and the Pallas kernel body execute the *same code*:
+"""The epoch core: the cost model's per-epoch stages as pure functions.
 
   shared_stage : row-buffer stamp-and-count, PEI top_k threshold + hot
-                 flags, access-EMA decay/update, page touch counts — the
-                 seed-invariant half of the cost model.
+                 flags, access-EMA decay/update, page touch counts -- the
+                 seed-invariant half, computed once per lane when the epoch
+                 driver shares it across seed replicas.
   route_stage  : effective-table gathers, technique scheduling (incl. PEI
                  hot-source placement and the AIMM compute-remap override),
                  per-link flit loads, hop counts, per-cube compute /
                  access / row-buffer-distinct counts and MC-queue depths.
   tom_stage    : TOM candidate co-location scores for one op window.
 
-`route_stage` comes in two flavors that are exactly equal in value and in
-bits: the gather/einsum form (the historical engine inline code, used by the
-jnp backend) and a one-hot matmul form (used inside the kernel body, where
-pair-indexed matmuls against the topology's `routes_flat`/`hops_flat`
-layouts map onto the MXU).  Exactness contract: every weight entering a
-reduction is an exact small integer (0/1 route incidence, 0/1 validity,
-integer hop counts) or an exact small-integer multiple of `packet_flits`,
-and all sums stay far below 2**24 — so scatter-adds, einsums and one-hot
-matmuls produce identical f32 bits under ANY reduction order.  The engine
-goldens (tests/test_engine_golden.py) and the parity suite
-(tests/test_pallas_parity.py) pin this.
-
-Layering note: this module imports `repro.nmp.baselines` (technique
-scheduling + TOM scoring) — the epoch kernel *is* the NMP epoch core, so
-unlike `dueling_qnet` it is not model-agnostic.
+Exactness contract: every weight entering a reduction is an exact small
+integer (0/1 route incidence, 0/1 validity, integer hop counts) or an exact
+small-integer multiple of `packet_flits`, and all sums stay far below
+2**24, so the scatter-adds and einsums give the same f32 bits under any
+reduction order.  The engine goldens (tests/test_engine_golden.py) pin it.
 """
 from __future__ import annotations
 
@@ -65,8 +52,7 @@ class RouteParts(NamedTuple):
 
 def shared_stage(dest, src1, src2, valid, epochs, rb_stamp, page_ema,
                  n_pages, pei_idx, *, pei_k: int, aimm: bool) -> SharedParts:
-    """Seed-invariant epoch quantities — bit-identical to the historical
-    inline computation in `engine._shared_epoch`."""
+    """Seed-invariant epoch quantities of one lane's window."""
     P = rb_stamp.shape[0] - 1
     W = dest.shape[0]
 
@@ -132,7 +118,7 @@ def route_stage(dest, src1, src2, valid, rb_winner, pei_hot1, pei_hot2,
                 pending_mig_loads, route_links, hops, nearest_mc, *,
                 pei: bool, aimm: bool, n_mcs: int,
                 packet_flits: float) -> RouteParts:
-    """Gather/einsum flavor — the historical engine inline code, verbatim."""
+    """Schedule, route and count one cell's window."""
     C = route_links.shape[0]
     dcube, s1cube, s2cube, ccube = _compute_cubes(
         dest, src1, src2, eff_table, compute_remap, technique, is_aimm,
@@ -160,68 +146,10 @@ def route_stage(dest, src1, src2, valid, rb_winner, pei_hot1, pei_hot2,
                       acc_c=acc_c, distinct_c=distinct_c, mcq=mcq)
 
 
-def _onehot(idx, n):
-    """(len(idx), n) f32 one-hot rows via broadcasted_iota (TPU-safe)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
-    return (idx[:, None] == iota).astype(jnp.float32)
-
-
-def _dot(a, b):
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
-
-
-def route_stage_onehot(dest, src1, src2, valid, rb_winner, pei_hot1,
-                       pei_hot2, eff_table, compute_remap, technique,
-                       is_aimm, pending_mig_loads, routes_flat, hops_flat,
-                       nearest_mc, *, pei: bool, aimm: bool, n_mcs: int,
-                       packet_flits: float) -> RouteParts:
-    """One-hot matmul flavor of `route_stage` for the kernel body: every
-    C- and (C*C)-indexed gather/scatter becomes a one-hot matmul against
-    the topology's pair-flattened tensors.  Bit-identical to the gather
-    flavor (each one-hot row selects exactly one table row; every reduction
-    sums exact small integers — see module doc)."""
-    C = nearest_mc.shape[0]
-    dcube, s1cube, s2cube, ccube = _compute_cubes(
-        dest, src1, src2, eff_table, compute_remap, technique, is_aimm,
-        pei_hot1, pei_hot2, C, pei=pei, aimm=aimm)
-
-    fsrc = jnp.concatenate([s1cube, s2cube, ccube])
-    fdst = jnp.concatenate([ccube, ccube, dcube])
-    fw = jnp.concatenate([valid, valid, valid]) * packet_flits
-    routes = _dot(_onehot(fsrc * C + fdst, C * C), routes_flat)  # (3W, L)
-    loads = _dot(fw.astype(jnp.float32), routes) + pending_mig_loads
-
-    hops_op = (_dot(_onehot(s1cube * C + ccube, C * C), hops_flat)
-               + _dot(_onehot(s2cube * C + ccube, C * C), hops_flat)
-               + _dot(_onehot(ccube * C + dcube, C * C), hops_flat))
-
-    ops_c = _dot(valid, _onehot(ccube, C))
-    acc_cube = jnp.concatenate([dcube, s1cube, s2cube])
-    acc_valid = jnp.concatenate([valid, valid, valid])
-    acc_oh = _onehot(acc_cube, C)                                # (3W, C)
-    distinct_c = _dot(rb_winner.astype(jnp.float32), acc_oh)
-    acc_c = _dot(acc_valid, acc_oh)
-    mc_oh = (nearest_mc[:, None]
-             == jax.lax.broadcasted_iota(jnp.int32, (C, n_mcs), 1)
-             ).astype(jnp.float32)                               # (C, M)
-    mcq = _dot(valid, _dot(_onehot(dcube, C), mc_oh))
-    return RouteParts(ccube=ccube, loads=loads, hops_op=hops_op, ops_c=ops_c,
-                      acc_c=acc_c, distinct_c=distinct_c, mcq=mcq)
-
-
 def tom_stage(dest, src1, src2, valid, cands, n_cubes: int) -> jnp.ndarray:
-    """(K,) TOM candidate co-location scores — vmap flavor (the historical
-    `engine._tom_window_scores` body, used by the jnp backend)."""
+    """(K,) TOM candidate co-location scores of one window."""
     def score_k(k):
         return tom_colocation_score(cands[k], dest, src1, src2, valid,
                                     n_cubes)
     return jax.vmap(score_k)(jnp.arange(cands.shape[0]))
 
-
-def tom_stage_loop(dest, src1, src2, valid, cands, n_cubes: int
-                   ) -> jnp.ndarray:
-    """Unrolled flavor for the kernel body (K is a static constant; a Python
-    loop avoids vmap-inside-kernel).  Same math per candidate."""
-    return jnp.stack([
-        tom_colocation_score(cands[k], dest, src1, src2, valid, n_cubes)
-        for k in range(cands.shape[0])])

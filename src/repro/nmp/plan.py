@@ -38,7 +38,6 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.kernels.epoch_fused import ops as epoch_ops
 from repro.nmp import baselines
 from repro.nmp.config import NMPConfig
 from repro.nmp.engine import (BodyFlags, make_ctx_host, pad_trace_ops_host,
@@ -234,7 +233,6 @@ def group_flags(group: Sequence[Scenario], cfg: NMPConfig,
         any_aimm=any(sc.mapper == "aimm" for sc in group),
         any_tom=any(sc.mapper == "tom" for sc in group),
         pei_k=pei_k,
-        epoch_backend=epoch_ops.resolve_backend(),
     )
 
 

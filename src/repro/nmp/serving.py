@@ -88,7 +88,6 @@ from typing import Sequence
 import jax
 import numpy as np
 
-from repro.kernels.epoch_fused import ops as epoch_ops
 from repro.nmp import baselines, partition
 from repro.nmp import faults as faults_mod
 from repro.nmp import plan as plan_mod
@@ -216,8 +215,7 @@ class MappingServer:
         self._episodes: int | None = (envelope.n_episodes
                                       if envelope is not None else None)
         self._flags = BodyFlags(has_agent=True, any_aimm=True, any_tom=False,
-                                pei_k=0,
-                                epoch_backend=epoch_ops.resolve_backend())
+                                pei_k=0)
         self._tom_cands = None
         self._pending = None             # prepared-but-unserved next tick
         # Memo of host-side per-lane batch arrays keyed by trace identity:

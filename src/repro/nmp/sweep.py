@@ -127,7 +127,7 @@ def staging_enabled() -> bool:
     return _env_choice(STAGING_KNOB, "on", STAGING_MODES) == "on"
 
 
-# Fail fast on typo'd knobs at import, like REPRO_EPOCH_BACKEND.
+# Fail fast on typo'd knobs at import.
 land_mode()
 staging_enabled()
 

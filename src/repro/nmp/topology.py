@@ -67,11 +67,6 @@ class Topology:
     nbr: np.ndarray            # (C, D) int32 neighbor table (self-padded)
     nbr_valid: np.ndarray      # (C, D) bool
     far: np.ndarray            # (C,) int32 "far" remap target
-    # Kernel-friendly layouts of the same tensors (see kernels/epoch_fused):
-    # pair-indexed flattenings so the fused epoch kernel can express the
-    # route gather + einsum as one-hot matmuls over a (C*C, ...) table.
-    routes_flat: np.ndarray    # (C*C, L) float32 == route_links.reshape
-    hops_flat: np.ndarray      # (C*C,) float32 == hops.reshape (exact ints)
 
     @property
     def max_degree(self) -> int:
@@ -211,10 +206,7 @@ def _finish(name: str, cfg: NMPConfig, edges: list[tuple[int, int]],
                     mc_cubes=tuple(int(m) for m in mc_cubes),
                     hops=hops.astype(np.int32), route_links=routes,
                     nearest_mc=_nearest_mc(hops, mc_cubes),
-                    nbr=nbr, nbr_valid=nbr_valid, far=far.astype(np.int32),
-                    routes_flat=np.ascontiguousarray(
-                        routes.reshape(C * C, len(edges))),
-                    hops_flat=hops.reshape(C * C).astype(np.float32))
+                    nbr=nbr, nbr_valid=nbr_valid, far=far.astype(np.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,6 @@
 """Per-kernel validation: shape/dtype sweeps + hypothesis, allclose vs the
-pure-jnp oracles (interpret mode executes the kernel body on CPU)."""
+pure-jnp oracles (interpret mode executes the kernel body on CPU), and the
+agent's Q-network against a float64 NumPy oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,8 +8,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.kernels.dueling_qnet.ops import qnet_forward
-from repro.kernels.dueling_qnet.ref import dueling_qnet_ref
+from repro.core import dqn
 from repro.kernels.flash_attention.ops import gqa_flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssd_scan.ops import ssd
@@ -21,23 +21,41 @@ def _rand(key, *shape, dtype=jnp.float32, scale=0.5):
 
 
 # ---------------------------------------------------------------------------
-# dueling qnet
+# dueling Q-network (`dqn.q_values`, the agent's forward pass)
 # ---------------------------------------------------------------------------
 
+def _dueling_oracle(x, p):
+    """Q = V + A - mean_a A through two ReLU layers, in float64 NumPy."""
+    f = {k: np.asarray(v, np.float64) for k, v in p.items()}
+    h = np.maximum(np.asarray(x, np.float64) @ f["w0"] + f["b0"], 0.0)
+    h = np.maximum(h @ f["w1"] + f["b1"], 0.0)
+    v = h @ f["w_v"] + f["b_v"]
+    a = h @ f["w_a"] + f["b_a"]
+    return v + a - a.mean(axis=-1, keepdims=True)
+
+
+_q_values = jax.jit(dqn.q_values, static_argnames="cfg")
+
+
+def _qnet_params(key, state_dim, hidden, actions):
+    shapes = {"w0": (state_dim, hidden), "b0": (hidden,),
+              "w1": (hidden, hidden), "b1": (hidden,),
+              "w_v": (hidden, 1), "b_v": (1,),
+              "w_a": (hidden, actions), "b_a": (actions,)}
+    return {k: _rand(key + i, *s) for i, (k, s) in enumerate(shapes.items())}
+
+
+# 106 is the 4x4 paper mesh agent's state width, 298 the 8x8 one's.
 @pytest.mark.parametrize("batch", [1, 37, 128, 300])
-@pytest.mark.parametrize("state_dim", [64, 106, 256])
+@pytest.mark.parametrize("state_dim", [64, 106, 298])
 def test_qnet_shapes(batch, state_dim):
     H, A = 128, 8
-    params = {"w0": _rand(0, state_dim, H), "b0": _rand(1, H),
-              "w1": _rand(2, H, H), "b1": _rand(3, H),
-              "w_v": _rand(4, H, 1), "b_v": _rand(5, 1),
-              "w_a": _rand(6, H, A), "b_a": _rand(7, A)}
+    cfg = dqn.DQNConfig(state_dim=state_dim, n_actions=A, hidden=(H, H))
+    params = _qnet_params(0, state_dim, H, A)
     x = _rand(8, batch, state_dim)
-    got = qnet_forward(params, x)
-    want = dueling_qnet_ref(x, params["w0"], params["b0"], params["w1"],
-                            params["b1"], params["w_v"], params["b_v"],
-                            params["w_a"], params["b_a"])
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    got = _q_values(params, x, cfg)
+    assert got.shape == (batch, A) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), _dueling_oracle(x, params),
                                rtol=1e-4, atol=1e-4)
 
 
@@ -45,16 +63,11 @@ def test_qnet_shapes(batch, state_dim):
 @given(st.integers(1, 64), st.integers(2, 12))
 def test_qnet_hypothesis(batch, actions):
     S, H = 32, 64
-    params = {"w0": _rand(10, S, H), "b0": _rand(11, H),
-              "w1": _rand(12, H, H), "b1": _rand(13, H),
-              "w_v": _rand(14, H, 1), "b_v": _rand(15, 1),
-              "w_a": _rand(16, H, actions), "b_a": _rand(17, actions)}
+    cfg = dqn.DQNConfig(state_dim=S, n_actions=actions, hidden=(H, H))
+    params = _qnet_params(10, S, H, actions)
     x = _rand(18, batch, S)
-    got = qnet_forward(params, x)
-    want = dueling_qnet_ref(x, params["w0"], params["b0"], params["w1"],
-                            params["b1"], params["w_v"], params["b_v"],
-                            params["w_a"], params["b_a"])
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    got = _q_values(params, x, cfg)
+    np.testing.assert_allclose(np.asarray(got), _dueling_oracle(x, params),
                                rtol=1e-4, atol=1e-4)
 
 

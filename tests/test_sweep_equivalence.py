@@ -7,6 +7,9 @@ its trace arrays — see nmp.plan).
 Grids are sized so related checks share one compiled sweep signature
 (same op/page envelope, episode count and agent mode => one XLA program).
 """
+import dataclasses
+
+import jax
 import numpy as np
 import pytest
 
@@ -17,6 +20,8 @@ from repro.nmp.scenarios import (Scenario, forced_action_grid, seed_variants,
 from repro.nmp.stats import summarize
 from repro.nmp.sweep import run_grid
 
+from tests.test_engine_golden import GOLDEN
+
 CFG = NMPConfig()
 
 
@@ -24,6 +29,33 @@ def _assert_exact(serial: dict, batched: dict, label: str):
     for key in ("cycles", "ops", "opc"):
         assert serial[key] == batched[key], (label, key, serial[key],
                                              batched[key])
+
+
+def _metrics_equal(a, b) -> bool:
+    return (set(a.metrics) == set(b.metrics)
+            and all(np.array_equal(np.asarray(a.metrics[k]),
+                                   np.asarray(b.metrics[k]))
+                    for k in a.metrics))
+
+
+@pytest.fixture(scope="module")
+def golden_grid():
+    """Every engine-golden lane (seed 2, forced actions as keyed) in one
+    `run_grid` call: the path the chip runs, padded to a common envelope."""
+    keys = sorted(GOLDEN)
+    traces = {(app, n): make_trace(app, n_ops=n) for app, n, *_ in keys}
+    grid = [Scenario(name="/".join(map(str, k)), trace=traces[k[:2]],
+                     technique=k[2], mapper=k[3], seed=2, forced_action=k[4])
+            for k in keys]
+    return keys, run_grid(grid, CFG)
+
+
+@pytest.mark.parametrize("key", sorted(GOLDEN),
+                         ids=lambda k: "/".join(map(str, k)))
+def test_grid_reproduces_engine_goldens(golden_grid, key):
+    keys, res = golden_grid
+    s = res.episode_summary(keys.index(key), 0)
+    assert (s["cycles"], s["ops"], s["opc"]) == GOLDEN[key], (key, s)
 
 
 def test_grid_matches_serial_deterministic_lanes():
@@ -217,3 +249,53 @@ def test_agent_counters_match_invoke_t():
     assert res.counters["agent_invocations"] == int(inv.sum())
     assert res.counters["agent_epochs"] == 2 * res.plan.n_epochs
     assert 0 < res.counters["agent_fires"] <= res.counters["agent_epochs"]
+
+
+def test_sweep_knobs_validate(monkeypatch):
+    from repro.nmp import sweep
+    monkeypatch.setenv(sweep.LAND_KNOB, "later")
+    with pytest.raises(ValueError, match="REPRO_SWEEP_LAND.*later"):
+        sweep.land_mode()
+    monkeypatch.setenv(sweep.LAND_KNOB, "sync")
+    assert sweep.land_mode() == "sync"
+    monkeypatch.delenv(sweep.LAND_KNOB, raising=False)
+    assert sweep.land_mode() == "async"
+
+    monkeypatch.setenv(sweep.STAGING_KNOB, "maybe")
+    with pytest.raises(ValueError, match="REPRO_STORE_STAGING.*maybe"):
+        sweep.staging_enabled()
+    monkeypatch.setenv(sweep.STAGING_KNOB, "off")
+    assert sweep.staging_enabled() is False
+    monkeypatch.delenv(sweep.STAGING_KNOB, raising=False)
+    assert sweep.staging_enabled() is True
+
+
+def test_async_land_and_staging_bit_identical(monkeypatch):
+    """Chained lineage run_grid calls under the defaults (async landing,
+    staging buffers) must produce bit-identical metrics AND final store
+    snapshots to the sync/per-cell path."""
+    from repro.nmp import sweep
+    grid = single_program_grid(apps=("KM", "PR"), mappers=("aimm",),
+                               n_ops=256, seeds=(0, 1), aimm_episodes=2)
+    grid += single_program_grid(apps=("KM",), mappers=("none",), n_ops=256,
+                                seeds=(0,))
+    grid = [dataclasses.replace(sc, lineage=f"lin{i}")
+            if sc.mapper == "aimm" else sc for i, sc in enumerate(grid)]
+
+    def chain():
+        r1 = sweep.run_grid(grid)
+        return r1, sweep.run_grid(grid, store=r1.store)
+
+    monkeypatch.setenv(sweep.LAND_KNOB, "sync")
+    monkeypatch.setenv(sweep.STAGING_KNOB, "off")
+    a1, a2 = chain()
+    monkeypatch.setenv(sweep.LAND_KNOB, "async")
+    monkeypatch.setenv(sweep.STAGING_KNOB, "on")
+    b1, b2 = chain()
+    assert _metrics_equal(a1, b1) and _metrics_equal(a2, b2)
+    sa, sb = a2.store, b2.store
+    assert sa.tags == sb.tags
+    for tag in sa.tags:
+        for x, y in zip(jax.tree.leaves(sa.get(tag)),
+                        jax.tree.leaves(sb.get(tag))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

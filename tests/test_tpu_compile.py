@@ -1,4 +1,4 @@
-"""Compiles for a described TPU v5e: the kernels and the sweep program of
+"""Compiles for a described TPU v5e: the Q-network and the sweep program of
 the default chip path, at production widths, with no chip attached.
 
 Nothing here runs; each test lowers and compiles for `v5e:2x2` device 0,
@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from repro.configs.aimm_nmp import PAPER_4X4
 from repro.core import dqn
 from repro.core.actions import N_ACTIONS
-from repro.kernels.epoch_fused import ops as epoch_ops
 from repro.nmp.engine import default_agent_cfg, state_spec_for
 
 STATE_DIM = state_spec_for(PAPER_4X4).dim
@@ -42,27 +41,10 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
-def _qnet_param_shapes(lead=()):
-    cfg = dqn.DQNConfig(state_dim=STATE_DIM, n_actions=N_ACTIONS,
-                        hidden=HIDDEN)
+def _qnet_param_shapes(cfg):
     shapes = jax.eval_shape(lambda: dqn.init_params(jax.random.PRNGKey(0),
                                                     cfg))
-    return {k: (lead + v.shape, v.dtype) for k, v in shapes.items()}
-
-
-@pytest.mark.parametrize("rows", [1, 64, 1024],
-                         ids=["act", "replay_batch", "rows1024"])
-def test_qnet_kernel_compiles(one_chip, rows):
-    """The dueling-qnet kernel at the paper's state width: one ε-greedy act
-    (1 row), one replay minibatch (64 rows), both padded to the 128-row
-    tile, and a multi-tile batch."""
-    from repro.kernels.dueling_qnet.ops import qnet_forward
-    params = {k: _sds(s, d, one_chip)
-              for k, (s, d) in _qnet_param_shapes().items()}
-    x = _sds((rows, STATE_DIM), jnp.float32, one_chip)
-    compiled = jax.jit(lambda p, s: qnet_forward(p, s, interpret=False)
-                       ).lower(params, x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return {k: (v.shape, v.dtype) for k, v in shapes.items()}
 
 
 def _kernel_names(hlo: str) -> list[str]:
@@ -70,6 +52,23 @@ def _kernel_names(hlo: str) -> list[str]:
     return [line.split("=", 1)[0].split()[-1].lstrip("%")
             for line in hlo.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("rows", [1, 64, 1024],
+                         ids=["act", "replay_batch", "rows1024"])
+def test_qnet_forward_compiles(one_chip, rows):
+    """The agent's forward pass (`dqn.q_values`) at the paper's state width:
+    one ε-greedy act (1 row), one replay minibatch (64 rows) and a wide
+    batch.  Every Pallas kernel in it is the order-fixed contraction."""
+    cfg = dqn.DQNConfig(state_dim=STATE_DIM, n_actions=N_ACTIONS,
+                        hidden=HIDDEN)
+    params = {k: _sds(s, d, one_chip)
+              for k, (s, d) in _qnet_param_shapes(cfg).items()}
+    x = _sds((rows, STATE_DIM), jnp.float32, one_chip)
+    hlo = jax.jit(lambda p, s: dqn.q_values(p, s, cfg)
+                  ).lower(params, x).compile().as_text()
+    names = _kernel_names(hlo)
+    assert names and all(n.startswith("order_fixed_dense") for n in names)
 
 
 @pytest.mark.parametrize("rows,k,n", [(64, STATE_DIM, 128), (64, 128, 128),
@@ -94,18 +93,12 @@ def test_order_fixed_dense_compiles(one_chip, rows, k, n):
     assert len(names) == 3 and all("order_fixed_dense" in n for n in names)
 
 
-def test_backends_auto_resolve_to_jnp(monkeypatch):
-    monkeypatch.delenv(epoch_ops.ENV_KNOB, raising=False)
-    assert epoch_ops.resolve_backend() == "jnp"
-
-
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["default-1chip",
                                                "default-4chips"])
-def test_sweep_program_compiles(topo, monkeypatch, n_dev):
+def test_sweep_program_compiles(topo, n_dev):
     """The learned-agent group of a paper-shaped grid (AIMM lanes of every
     technique, PEI's top-k live) through plan -> build batch -> `_run_sweep`
-    with the defaults, on one chip and sharded over four.  The epoch core is
-    jnp on both.  On one chip the Q-network's contractions are the
+    with the defaults, on one chip and sharded over four.  On one chip the Q-network's contractions are the
     order-fixed kernel, the program's only Pallas kernel; sharded, GSPMD
     cannot split that kernel, so the program keeps the jnp contractions and
     each chip holds only its own cells' agents."""
@@ -114,8 +107,6 @@ def test_sweep_program_compiles(topo, monkeypatch, n_dev):
     from repro.nmp import plan as plan_mod
     from repro.nmp import sweep
     from repro.nmp.scenarios import single_program_grid
-
-    monkeypatch.delenv(epoch_ops.ENV_KNOB, raising=False)
 
     cfg = PAPER_4X4
     grid = single_program_grid(apps=("KM", "SPMV"),
@@ -129,7 +120,7 @@ def test_sweep_program_compiles(topo, monkeypatch, n_dev):
         -(-group.n_lanes // n_dev) * n_dev)
     tom = np.asarray(plan_mod.plan_tom_candidates(plan, cfg))
     flags = sweep.executed_flags(group, group.n_seeds)
-    assert flags.epoch_backend == "jnp" and flags.pei_k > 0
+    assert flags.pei_k > 0
 
     mesh = Mesh(np.asarray(topo.devices[:n_dev]).reshape(n_dev, 1),
                 (partition.LANE_AXIS, partition.SEED_AXIS))
