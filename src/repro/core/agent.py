@@ -168,8 +168,12 @@ def act(agent: AgentState, cfg: AgentConfig, state_vec: jnp.ndarray,
                                   global_step=agent.global_step + 1)
 
 
-def observe(agent: AgentState, s, a, r, s2, done=0.0) -> AgentState:
-    return agent._replace(replay=push(agent.replay, s, a, r, s2, done))
+def observe(agent: AgentState, s, a, r, s2, done=0.0,
+            where=True) -> AgentState:
+    """Append (s, a, r, s2, done) to the replay ring where `where` holds;
+    elsewhere the agent is returned unchanged (`replay.push`)."""
+    return agent._replace(replay=push(agent.replay, s, a, r, s2, done,
+                                      where))
 
 
 def replay_ready(agent: AgentState, cfg: AgentConfig) -> jnp.ndarray:

@@ -35,18 +35,28 @@ def init_replay(capacity: int, state_dim: int) -> ReplayBuffer:
     )
 
 
-def push(buf: ReplayBuffer, s, a, r, s2, done) -> ReplayBuffer:
+def push(buf: ReplayBuffer, s, a, r, s2, done, where=True) -> ReplayBuffer:
+    """Write one transition at `ptr` and advance the ring.  Where `where` is
+    false the row at `ptr` is written back with its own contents and `ptr`
+    and `size` are kept: the buffer is unchanged bit for bit, and a batched
+    caller writes one row per cell in place instead of selecting between two
+    whole buffers."""
     cap = buf.s.shape[0]
     i = buf.ptr
-    s, a, r = (jnp.asarray(x) for x in (s, a, r))
+    where = jnp.asarray(where, jnp.bool_)
+
+    def put(col, x):
+        x = jnp.asarray(x).astype(col.dtype)
+        return col.at[i].set(jnp.where(where, x, col[i]))
+
     return ReplayBuffer(
-        s=buf.s.at[i].set(s.astype(jnp.float32)),
-        a=buf.a.at[i].set(a.astype(jnp.int32)),
-        r=buf.r.at[i].set(r.astype(jnp.float32)),
-        s2=buf.s2.at[i].set(s2.astype(jnp.float32)),
-        done=buf.done.at[i].set(jnp.asarray(done, jnp.float32)),
-        ptr=(i + 1) % cap,
-        size=jnp.minimum(buf.size + 1, cap),
+        s=put(buf.s, s),
+        a=put(buf.a, a),
+        r=put(buf.r, r),
+        s2=put(buf.s2, s2),
+        done=put(buf.done, done),
+        ptr=jnp.where(where, (i + 1) % cap, i),
+        size=jnp.where(where, jnp.minimum(buf.size + 1, cap), buf.size),
     )
 
 

@@ -886,8 +886,9 @@ def _invoke_agent(agent: AgentState, svec: jnp.ndarray, reward: jnp.ndarray,
                   commit: jnp.ndarray, prev_ok: jnp.ndarray,
                   agent_cfg: AgentConfig, agent_gate: str):
     """Batched continual-learning invocation (Fig. 4-2 flow): the completed
-    transition (s_{t-1}, a_{t-1}, r_{t-1}, s_t) enters the replay buffer, the
-    DNN takes one minibatch TD step, and ε-greedy inference picks the next
+    transition (s_{t-1}, a_{t-1}, r_{t-1}, s_t) enters the replay buffer
+    (one row written in place per committing cell), the DNN takes one
+    minibatch TD step, and ε-greedy inference picks the next
     action.  Every argument carries one flat leading cell axis — the epoch
     driver flattens (lane, seed) grids down to it, so the agent machinery is
     written once for both layouts.
@@ -903,9 +904,9 @@ def _invoke_agent(agent: AgentState, svec: jnp.ndarray, reward: jnp.ndarray,
     equals the compute-then-mask reference path (tests/test_engine_golden.py).
     """
     with jax.named_scope("agent.observe"):
-        pushed = jax.vmap(agent_mod.observe)(agent, prev_svec, prev_action,
-                                             reward, svec)
-        ag = _sel(commit & prev_ok, pushed, agent)
+        ag = jax.vmap(lambda al, s, a, r, s2, m: agent_mod.observe(
+            al, s, a, r, s2, where=m))(agent, prev_svec, prev_action, reward,
+                                       svec, commit & prev_ok)
     keys = jax.vmap(jax.random.split)(ag.rng)          # (B, 2, key)
     ag = ag._replace(rng=jnp.where(commit[:, None], keys[:, 0], ag.rng))
     k_train = keys[:, 1]

@@ -210,6 +210,30 @@ def test_learned_timelines_match_serial_with_seed_axis():
     assert ((m["action_t"][:3] != 0) <= inv).all()
 
 
+def test_mixed_commit_fires_match_serial_agents():
+    """Learned cells of unequal length and two seeds each, so that in one
+    fire some cells commit a transition and others do not (finished,
+    between invocations, or with no previous span yet): every cell's final
+    agent, replay included, equals its serial `run_episode` bit for bit."""
+    grid = [Scenario(name=f"{app}/s{s}", trace=make_trace(app, n_ops=n),
+                     mapper="aimm", seed=s, lineage=f"{app}-s{s}")
+            for app, n in (("KM", 512), ("SPMV", 1024)) for s in (0, 1)]
+    res = run_grid(grid, CFG)
+    inv = res.metrics["invoke_t"][:, 0] > 0                # (cells, epochs)
+    assert (inv.any(axis=0) & ~inv.all(axis=0)).any()      # mixed fires
+    for sc in grid:
+        serial = run_episode(sc.trace, CFG, sc.technique, "aimm",
+                             seed=sc.seed).agent
+        got = res.store.get(sc.lineage)
+        assert int(got.replay.size) > 0
+        want = jax.tree_util.tree_leaves_with_path(serial)
+        assert jax.tree.structure(got) == jax.tree.structure(serial)
+        for (path, w), g in zip(want, jax.tree.leaves(got)):
+            np.testing.assert_array_equal(
+                np.asarray(g), np.asarray(w),
+                err_msg=f"{sc.name}{jax.tree_util.keystr(path)}")
+
+
 def test_baseline_grid_lands_no_timelines(monkeypatch):
     """A none/tom grid neither computes nor fetches the AIMM timelines or
     the agent counters: it fetches the statistics it fetched before."""
